@@ -111,7 +111,8 @@ async def _drive(engine, arrivals, prompts, n_decode: int,
 
 
 def _build_engine(quick: bool):
-    from repro.core.esn import ESNConfig, LinearESN
+    from repro.core import esn as esn_fn
+    from repro.core.esn import ESNConfig
     from repro.data.signals import mso_series
     from repro.serve import ReservoirEngine
 
@@ -120,8 +121,10 @@ def _build_engine(quick: bool):
                     seed=7)
     sig = mso_series(3, 1201)
     u, y = sig[:-1, None], sig[1:, None]
-    model = LinearESN.diagonalized(cfg).fit(u[:600], y[:600], washout=50)
-    eng = ReservoirEngine(model, max_slots=4 if quick else 8,
+    params = esn_fn.diag_params(cfg)
+    readout = esn_fn.fit_host(params, u[:600], y[:600], washout=50)
+    eng = ReservoirEngine(params, readout=readout,
+                          max_slots=4 if quick else 8,
                           max_queued=16 if quick else 64,
                           tracker=f"jsonl:{TRACE_PATH}")
     return eng, u, y

@@ -139,8 +139,8 @@ def fused_decode_cost(n=512, b=8, k=16, d=1, seed=0):
     params = esn_fn.dpg_params(cfg, "noisy_golden", sigma=0.1)
     rng = np.random.default_rng(seed)
     sig = np.sin(0.2 * np.arange(1501)) + rng.normal(0, 0.05, 1501)
-    w_out = esn_fn.fit(params, sig[:-1, None], sig[1:, None],
-                       washout=100).w_out
+    w_out = esn_fn.fit_host(params, sig[:-1, None], sig[1:, None],
+                            washout=100).w_out
     use_fb = params.cfg.use_feedback
     w_drive = params.win_q + params.wfb_q if use_fb else params.win_q
     dt = params.lam_q.dtype

@@ -90,7 +90,8 @@ def _build(n):
                     ridge_alpha=1e-8, seed=0)
     params = esn_fn.dpg_params(cfg, "noisy_golden", sigma=0.1)
     sig = mso_series(3, 2001)
-    readout = esn_fn.fit(params, sig[:-1, None], sig[1:, None], washout=100)
+    readout = esn_fn.fit_host(params, sig[:-1, None], sig[1:, None],
+                               washout=100)
     return params, readout, sig
 
 
@@ -591,8 +592,8 @@ def main(quick: bool = False):
     re_cfg = ESNConfig(n=n, spectral_radius=0.95, leak=0.9,
                        input_scaling=0.5, ridge_alpha=1.0, seed=0)
     re_params = esn_fn.dpg_params(re_cfg, "noisy_golden", sigma=0.01)
-    re_readout = esn_fn.fit(re_params, sig[:-1, None], sig[1:, None],
-                            washout=100)
+    re_readout = esn_fn.fit_host(re_params, sig[:-1, None], sig[1:, None],
+                                 washout=100)
     # Post-shift regime: frequencies DISJOINT from the trained MSO set —
     # mso_series(k-1) would be a spectral subset the linear readout predicts
     # perfectly, i.e. no drift at all.

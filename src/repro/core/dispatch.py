@@ -30,6 +30,7 @@ only on ``core.scan`` + ``kernels``) and is imported directly — the old
 """
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
@@ -46,7 +47,31 @@ __all__ = [
     "run_scan_q",
     "resolve_decode_method",
     "run_decode_fused",
+    "device_fn",
 ]
+
+
+def device_fn(fn, mesh=None):
+    """``fn`` traced the way every jitted device function of the engine is.
+
+    * Full-precision dots: on the TPU a float32 dot at default precision
+      runs as one bfloat16 pass, below the float32 the serving path states.
+      The CPU computes float32/float64 dots in full either way.
+    * With ``mesh`` (the arena's device mesh) in context, so the Pallas
+      wrappers run per device over the slot axis (``kernels.ops``): Mosaic
+      kernels are not partitioned automatically.
+    """
+    @functools.wraps(fn)
+    def traced(*args, **kwargs):
+        with jax.default_matmul_precision("highest"):
+            if mesh is None:
+                return fn(*args, **kwargs)
+            with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+                return fn(*args, **kwargs)
+    # Executables and profiler events carry the wrapped function's name.
+    traced.__name__ = getattr(fn, "__name__", None) or fn.func.__name__
+    return traced
+
 
 # Thresholds in steps along the time axis.  Calibrated coarsely: the
 # crossover constants differ per backend, but the *ordering* of regimes does

@@ -59,6 +59,7 @@ __all__ = [
     "features",
     "eet_metric",
     "fit",
+    "fit_host",
     "predict",
     "generate",
 ]
@@ -282,6 +283,55 @@ def fit(params, u, y, washout: int = 0, alpha: Optional[float] = None,
         return Readout(ridge_mod.ridge_solve(g, c, alpha))
     return Readout(ridge_mod.ridge_solve_general(g, c, eet_metric(params),
                                                  alpha))
+
+
+def fit_host(params: DiagParams, u, y, washout: int = 0,
+             alpha: Optional[float] = None) -> Readout:
+    """:func:`fit` for a diag-mode model, run on the host in numpy float64.
+
+    The offline readout fit of the serving deployments.  The device path
+    runs in float32, and ridge alphas down to 1e-8 are not well posed in
+    float32 normal equations; so the states are recomputed here in float64
+    from the params as stored (float32 on the chip), the EET-regularized
+    system (Eq. 29) is solved in float64, and the readout is returned in the
+    params' dtype — what serving applies."""
+    if params.mode != "diag":
+        raise ValueError("fit_host takes diag-mode params")
+    cfg = params.cfg
+    alpha = cfg.ridge_alpha if alpha is None else alpha
+    nr = params.n_real
+    u = np.asarray(u, np.float64)
+    y = np.asarray(y, np.float64)
+    d = u @ np.asarray(params.win_q, np.float64)
+    y_prev = None
+    if cfg.use_feedback:
+        y_prev = np.concatenate([np.zeros((1, cfg.d_out)), y[:-1]], axis=0)
+        d = d + y_prev @ np.asarray(params.wfb_q, np.float64)
+
+    def to_complex(v):        # packed Q layout -> complex lanes
+        return np.concatenate([v[..., :nr], v[..., nr::2] + 1j * v[..., nr + 1::2]],
+                              axis=-1)
+
+    lam = to_complex(np.asarray(params.lam_q, np.float64))
+    d_c = to_complex(d)
+    h = np.zeros_like(lam)
+    hs = np.empty_like(d_c)
+    for t in range(d_c.shape[0]):
+        h = lam * h + d_c[t]
+        hs[t] = h
+    states = np.empty_like(d)
+    states[:, :nr] = hs[:, :nr].real
+    states[:, nr::2] = hs[:, nr:].real
+    states[:, nr + 1::2] = hs[:, nr:].imag
+    cols = [np.ones((len(d), 1))] if cfg.use_bias else []
+    if cfg.use_feedback:
+        cols.append(y_prev)
+    x = np.concatenate(cols + [states], axis=1)[washout:]
+    n_extra = x.shape[1] - cfg.n
+    metric = np.eye(x.shape[1])
+    metric[n_extra:, n_extra:] = np.asarray(params.qtq, np.float64)
+    w = np.linalg.solve(x.T @ x + alpha * metric, x.T @ y[washout:])
+    return Readout(jnp.asarray(w, params.dtype))
 
 
 def predict(params, readout: Readout, u, y_teacher=None,

@@ -116,12 +116,8 @@ def flash_attention_pallas(q, k, v, *, causal: bool = True,
         block_q=block_q, block_k=block_k, kv_tiles=kv_tiles)
     kw = {}
     if not interpret:
-        try:
-            kw["compiler_params"] = pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel", "arbitrary"))
-        except AttributeError:
-            kw["compiler_params"] = pltpu.TPUCompilerParams(
-                dimension_semantics=("parallel", "parallel", "arbitrary"))
+        kw["compiler_params"] = pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"))
     out = pl.pallas_call(
         kernel,
         grid=grid,

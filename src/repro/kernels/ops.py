@@ -13,9 +13,11 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from . import ref as ref_mod
-from .diag_scan import decode_fused_pallas_raw, diag_scan_pallas_raw
+from .diag_scan import (SLOT_TILE, decode_fused_pallas_raw,
+                        diag_scan_pallas_raw)
 from .flash_attention import flash_attention_pallas
 
 __all__ = ["diag_scan", "decode_fused", "flash_attention"]
@@ -23,6 +25,27 @@ __all__ = ["diag_scan", "decode_fused", "flash_attention"]
 
 def _round_up(x, m):
     return (x + m - 1) // m * m
+
+
+def _data_shards() -> int:
+    """Devices on the ``data`` axis of the mesh in context, 1 without one.
+    The batch / slot axis of every kernel operand rides that axis
+    (``sharding.rules.plan_arena``)."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or "data" not in mesh.axis_names:
+        return 1
+    return mesh.shape["data"]
+
+
+def _per_device(kernel, in_specs, out_specs, *args):
+    """Mosaic kernels are not partitioned automatically.  With a mesh in
+    context, run ``kernel`` under ``shard_map``: each device on its own
+    block of rows (``P("data")``) and a whole copy of every ``P()``
+    operand."""
+    if _data_shards() == 1:
+        return kernel(*args)
+    return jax.shard_map(kernel, in_specs=in_specs, out_specs=out_specs,
+                         check_vma=False)(*args)
 
 
 def diag_scan(a, x, h0=None, *, block_b: int = 8, block_t: int = 256,
@@ -56,14 +79,18 @@ def _scan_padded(a_full, x, h0, block_b, block_t, block_n, interpret):
     a_re, a_im = _split(a_full, real_dtype)
     x_re, x_im = _split(x, real_dtype)
     h_re, h_im = _split(h0, real_dtype)
-    bp, tp, np_ = _round_up(b, block_b), _round_up(t, block_t), _round_up(n, block_n)
+    bp = _round_up(b, block_b * _data_shards())
+    tp, np_ = _round_up(t, block_t), _round_up(n, block_n)
     pad = ((0, bp - b), (0, tp - t), (0, np_ - n))
     hpad = ((0, bp - b), (0, np_ - n))
     args = [jnp.pad(v, pad) for v in (a_re, a_im, x_re, x_im)]
     h0s = [jnp.pad(v, hpad) for v in (h_re, h_im)]
-    o_re, o_im = diag_scan_pallas_raw(
-        *args, *h0s, block_b=block_b, block_t=block_t, block_n=block_n,
-        interpret=interpret)
+    rows = P("data")
+    o_re, o_im = _per_device(
+        functools.partial(diag_scan_pallas_raw, block_b=block_b,
+                          block_t=block_t, block_n=block_n,
+                          interpret=interpret),
+        (rows,) * 6, (rows, rows), *args, *h0s)
     o_re, o_im = o_re[:b, :t, :n], o_im[:b, :t, :n]
     if is_cpx:
         return jax.lax.complex(o_re, o_im).astype(out_dtype)
@@ -126,37 +153,49 @@ def decode_fused(a_re, a_im, h_re, h_im, y0, wd_re, wd_im, wy, b_out, wh_re,
     """K-token fused closed-loop decode through the Pallas kernel.
 
     Accepts the same shared-or-batched realified-lane operands as
-    ``ref.decode_fused_ref``; broadcasts shared weights to a slot batch and
-    pads (B -> sublane, NC/D -> lane multiples) before the kernel call.  All
-    padding is inert: padded slots carry a zero mask (frozen zero rows,
-    excluded from the ensemble mean) and padded lanes carry zero weights.
+    ``ref.decode_fused_ref`` and pads them (B -> sublane, NC/D -> lane
+    multiples) before the kernel call.  Shared operands stay shared — the
+    kernel reads them as one block for every slot tile — so only per-slot
+    operands grow with B.  All padding is inert: padded slots carry a zero
+    mask (frozen zero rows, excluded from the ensemble mean) and padded
+    lanes carry zero weights.
     """
     b, nc = h_re.shape
     d = y0.shape[-1]
-    bp, ncp, dp = _round_up(b, 8), _round_up(nc, 128), _round_up(d, 128)
-
-    def bcast(w, shape):
-        return jnp.broadcast_to(w, shape) if w.ndim < len(shape) else w
-
-    wd_re = bcast(wd_re, (b, d, nc))
-    wd_im = bcast(wd_im, (b, d, nc))
-    wy = bcast(wy, (b, d, d))
-    b_out = bcast(b_out, (b, d))
-    wh_re = bcast(wh_re, (b, nc, d))
-    wh_im = bcast(wh_im, (b, nc, d))
-    a_re, a_im = bcast(a_re, (b, nc)), bcast(a_im, (b, nc))
-
+    # The mean ensemble couples every slot at every step: each device then
+    # runs the whole (replicated) block.
+    shards = 1 if ensemble == "mean" else _data_shards()
+    bp = _round_up(b, SLOT_TILE * shards)
+    ncp, dp = _round_up(nc, 128), _round_up(d, 128)
     pb, pn, pd = (0, bp - b), (0, ncp - nc), (0, dp - d)
-    args = (jnp.pad(a_re, (pb, pn)), jnp.pad(a_im, (pb, pn)),
+
+    def rows(v, lanes):
+        """(X,) shared -> (1, X'); (B, X) per slot -> (B', X')."""
+        return jnp.pad(v[None] if v.ndim == 1 else v,
+                       ((0, 0) if v.ndim == 1 else pb, lanes))
+
+    def mat(w, r, c):
+        """(R, C) shared or (B, R, C) per slot, padded to (R', C')."""
+        return jnp.pad(w, (r, c) if w.ndim == 2 else (pb, r, c))
+
+    args = (rows(a_re, pn), rows(a_im, pn),
             jnp.pad(h_re, (pb, pn)), jnp.pad(h_im, (pb, pn)),
             jnp.pad(y0, (pb, pd)),
-            jnp.pad(wd_re, (pb, pd, pn)), jnp.pad(wd_im, (pb, pd, pn)),
-            jnp.pad(wy, (pb, pd, pd)), jnp.pad(b_out, (pb, pd)),
-            jnp.pad(wh_re, (pb, pn, pd)), jnp.pad(wh_im, (pb, pn, pd)))
+            mat(wd_re, pd, pn), mat(wd_im, pd, pn), mat(wy, pd, pd),
+            rows(b_out, pd), mat(wh_re, pn, pd), mat(wh_im, pn, pd))
     m = jnp.pad(jnp.broadcast_to(
         jnp.asarray(mask, y0.dtype)[:, None], (b, 128)), (pb, (0, 0)))
-    o_re, o_im, y, ys = decode_fused_pallas_raw(
-        *args, m, k=k, ensemble=ensemble, interpret=interpret)
+    # Per-slot operands are split by rows over the devices; shared operands
+    # go whole to every device.
+    row = P() if ensemble == "mean" else P("data")
+    per_slot = (a_re.ndim == 2, a_im.ndim == 2, True, True, True,
+                wd_re.ndim == 3, wd_im.ndim == 3, wy.ndim == 3,
+                b_out.ndim == 2, wh_re.ndim == 3, wh_im.ndim == 3, True)
+    o_re, o_im, y, ys = _per_device(
+        functools.partial(decode_fused_pallas_raw, k=k, ensemble=ensemble,
+                          interpret=interpret),
+        tuple(row if s else P() for s in per_slot),
+        (row, row, row, P(None, *row)), *args, m)
     return o_re[:b, :nc], o_im[:b, :nc], y[:b, :d], ys[:, :b, :d]
 
 

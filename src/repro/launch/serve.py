@@ -70,29 +70,58 @@ import numpy as np
 
 
 # ---------------------------------------------------------------- reservoir
+#: Noise on the DPG golden spectrum.  The paper's 0.2, and 0.1, push
+#: max|lambda| past 1 at N >= 256: a reservoir that diverges over a long
+#: stream.  0.01 keeps it within 0.956 at N=1024 (seeds 0-5).
+DPG_SIGMA = 0.01
+
+
+def mso_deployment(n: int, seed: int, train_t: int = 2000):
+    """The repo's reservoir deployment: one-step-ahead forecasting of the
+    3-sine MSO signal.  Returns its ``ESNConfig`` and a training signal of
+    ``train_t + 1`` samples."""
+    from repro.core.esn import ESNConfig
+    from repro.data.signals import mso_series
+    cfg = ESNConfig(n=n, spectral_radius=0.95, leak=0.9, input_scaling=0.5,
+                    ridge_alpha=1e-8, seed=seed)
+    return cfg, mso_series(3, train_t + 1)
+
+
+def build_reservoir_engine(cfg, sig, *, slots: int, **engine_kw):
+    """DPG ``noisy_golden`` params for ``cfg`` (noise :data:`DPG_SIGMA`), a
+    readout fit to ``sig`` on the host in float64 (``core.esn.fit_host``),
+    and a ``ReservoirEngine`` of ``slots`` slots over them.  The engine
+    serves in the params' dtype: float32 unless x64 is on."""
+    from repro.core import esn as esn_fn
+    from repro.serve import ReservoirEngine
+    params = esn_fn.dpg_params(cfg, "noisy_golden", sigma=DPG_SIGMA)
+    readout = esn_fn.fit_host(params, sig[:-1, None], sig[1:, None],
+                              washout=100)
+    return ReservoirEngine(params, max_slots=slots, readout=readout,
+                           **engine_kw)
+
+
 def serve_reservoir(args) -> None:
     """Streaming session serving through ``serve.engine.ReservoirEngine``.
 
     The model is the pytree-native param API: an immutable ``DiagParams``
-    struct from ``dpg_params`` plus a pure-function-trained ``Readout``.
-    ``--ensemble`` builds one independently-seeded reservoir *per slot*
-    (``stack_params``) and serves them all from a single ``vmap``-ed decode
-    trace (``ReservoirEngine.from_param_batch``)."""
-    jax.config.update("jax_enable_x64", True)
+    struct from ``dpg_params`` plus a ``Readout`` fit on the host in float64.
+    The device path runs in float32.  ``--ensemble`` builds one
+    independently-seeded reservoir *per slot* (``stack_params``) and serves
+    them all from a single ``vmap``-ed decode trace
+    (``ReservoirEngine.from_param_batch``)."""
     import dataclasses
 
     from repro.core import esn as esn_fn
-    from repro.core.esn import ESNConfig
     from repro.core.params import Readout, stack_params
-    from repro.data.signals import mso_series
+    from repro.launch.runtime import enable_compile_cache
     from repro.serve import ReservoirEngine, WaveCostModel, cost_key
 
-    cfg = ESNConfig(n=args.n, spectral_radius=0.95, leak=0.9,
-                    input_scaling=0.5, ridge_alpha=1e-8, seed=args.seed)
+    enable_compile_cache()
     # Signal long enough for any requested prompt window AND the one-step-
     # ahead continuation the ensemble demo scores against.
     train_t = max(2000, args.prompt_len + args.gen + 512)
-    sig = mso_series(3, train_t + 1)
+    cfg, sig = mso_deployment(args.n, args.seed, train_t)
     u_train, y_train = sig[:-1, None], sig[1:, None]
 
     mesh = None
@@ -159,10 +188,10 @@ def serve_reservoir(args) -> None:
                          "per-session ensembles on drift instead)")
     if args.ensemble:
         batch = [esn_fn.dpg_params(dataclasses.replace(cfg, seed=args.seed + i),
-                                   "noisy_golden", sigma=0.1)
+                                   "noisy_golden", sigma=DPG_SIGMA)
                  for i in range(args.slots)]
         params = stack_params(batch)
-        readouts = [esn_fn.fit(p, u_train, y_train, washout=100).w_out
+        readouts = [esn_fn.fit_host(p, u_train, y_train, washout=100).w_out
                     for p in batch]
         readout = Readout(jnp.stack(readouts))
         engine = ReservoirEngine.from_param_batch(
@@ -187,14 +216,12 @@ def serve_reservoir(args) -> None:
             print("  member val-RMSE: "
                   + ", ".join(f"{r:.3e}" for r in rmses))
     else:
-        params = esn_fn.dpg_params(cfg, "noisy_golden", sigma=0.1)
-        readout = esn_fn.fit(params, u_train, y_train, washout=100)
         if args.learn:
             engine_kw.update(learn=True,
                              refit_decay=args.refit_decay,
                              drift_threshold=args.drift_threshold)
-        engine = ReservoirEngine(params, max_slots=args.slots,
-                                 readout=readout, **engine_kw)
+        engine = build_reservoir_engine(cfg, sig, slots=args.slots,
+                                        **engine_kw)
 
     if args.ensemble in ("mean", "weighted"):
         # One logical stream, B reservoirs voting: same prompt everywhere,

@@ -22,7 +22,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro import jax_compat
 from repro.core import scan as scan_mod
 from repro.core import spectral
 from . import attention as attn_mod
@@ -363,8 +362,8 @@ def apply_moe(p, x, cfg, prof: ShardProfile):
                 P(tp, None, fs), P(tp, None, fs), P(tp, fs, None))
     out_specs = (tok_out_spec,
                  {"load_balance": P(), "router_z": P()})
-    fn = jax_compat.shard_map(shard_fn, mesh=prof.mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_vma=False)
+    fn = jax.shard_map(shard_fn, mesh=prof.mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)
     out, aux = fn(x2d, p["router"], p["wg"], p["wu"], p["wd"])
     return out.reshape(b, s, d), aux
 

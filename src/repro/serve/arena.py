@@ -300,47 +300,53 @@ def closed_loop_fused(params, w_out, arena: SlotArena, mask, n_steps: int,
 
 
 # ------------------------------------------------------------- wave prefill
-def _row_prefill(params, w_out, cfg, h0, y0, u, y_teacher, length, *,
-                 method: str, chunk: int, want_outputs: bool):
-    """Prefill ONE row of a wave: scan the padded (T_bucket, D_in) prompt and
-    gather the state/output at the row's true last step.
+def _rows_prefill(params, w_out, cfg, h0, y0, u, y_teacher, lengths, *,
+                  method: str, chunk: int, want_outputs: bool):
+    """Prefill a block of rows of a wave: ONE scan of the padded
+    (B, T_bucket, D_in) prompts, then gather each row's state/output at its
+    true last step.
 
     The scan runs over the full padded length, but the recurrence is causal:
     nothing at t >= length can reach ``states[length - 1]``, so the gathered
     final state (and the y_prev seed) are exactly what an unpadded prefill
     produces — padding is inert by construction, not by masking arithmetic.
-    Per-step outputs past the true length are zeroed.
+    Per-step outputs past the true length are zeroed.  ``w_out`` is one
+    (F, D) readout or a (B, F, D) readout per row.
     """
     y_shift = None
     if cfg.use_feedback:
-        y_shift = jnp.concatenate([y0[None], y_teacher[:-1]], axis=0)
+        y_shift = jnp.concatenate([y0[:, None], y_teacher[:, :-1]], axis=1)
     states = esn_fn.scan_states(params, esn_fn.drive(params, u, y_shift),
                                 h0, method=method, chunk=chunk)
-    last = jax.lax.dynamic_index_in_dim(states, length - 1, keepdims=False)
-    valid = (jnp.arange(u.shape[0]) < length)[:, None]
+    last_idx = (lengths - 1)[:, None, None]
+
+    def at_last(v):
+        return jnp.take_along_axis(v, last_idx, axis=1)[:, 0]
+
+    last = at_last(states)
+    valid = (jnp.arange(u.shape[1])[None, :] < lengths[:, None])[..., None]
     if cfg.use_feedback:
         # Prefill is teacher-forced end-to-end: the teacher's last *true*
         # output is the feedback seed (parity with core.esn.run).
-        y_next = jax.lax.dynamic_index_in_dim(y_teacher, length - 1,
-                                              keepdims=False)
+        y_next = at_last(y_teacher)
     if w_out is None:
         out = jnp.where(valid, states, 0) if want_outputs else None
         return last, (y_next if cfg.use_feedback else y0), out
     y_last = None
     if want_outputs:
         x = esn_fn.assemble_features(params, states, y_shift)
-        y = x @ w_out
+        y = (x @ w_out if w_out.ndim == 2
+             else jnp.einsum("btf,bfd->btd", x, w_out))
         out = jnp.where(valid, y, 0)
         if not cfg.use_feedback:         # feedback models seed from y_next
-            y_last = jax.lax.dynamic_index_in_dim(y, length - 1,
-                                                  keepdims=False)
+            y_last = at_last(y)
     else:
         # Last-step readout only: O(N) — just the closed-loop feedback seed
         # (feedback models need none: the teacher's last output wins).
         out = None
         if not cfg.use_feedback:
-            x_last = esn_fn.assemble_features(params, last[None], None)
-            y_last = (x_last @ w_out)[0]
+            y_last = apply_readout(
+                w_out, esn_fn.assemble_features(params, last, None))
     return last, (y_next if cfg.use_feedback else y_last), out
 
 
@@ -355,9 +361,10 @@ def prefill_wave(params, w_out, arena: SlotArena, slots, u, lengths,
     lengths; ``y_teacher``: (B_wave, T_bucket, D_out) teacher outputs for
     feedback models (padding rows past ``lengths`` are ignored).
 
-    One ``vmap``-ed scan serves the whole wave — with shared params the rows
-    ride as a batch axis through the time-parallel backend; with a param
-    batch each row first slices its own reservoir out of the stack.  Returns
+    One scan serves the whole wave — with shared params the rows ride as the
+    batch axis of a single (B_wave, T_bucket, N) scan (one kernel launch on
+    the Pallas path); with a param batch a ``vmap`` slices each row's own
+    reservoir out of the stack.  Returns
     ``(arena', outputs)`` where outputs is (B_wave, T_bucket, D_out)
     per-step predictions ((B_wave, T_bucket, N) states when ``w_out`` is
     None), zeroed past each row's true length, or None when
@@ -383,32 +390,33 @@ def prefill_wave(params, w_out, arena: SlotArena, slots, u, lengths,
     kw = dict(method=method, chunk=chunk, want_outputs=want_outputs)
 
     if batched:
+        # Row b runs reservoir ``slots[b]``: slice it out of the stack and
+        # prefill each row as a block of one.
         def one(slot, h0_r, y0_r, u_r, yt_r, length):
             p = jax.tree_util.tree_map(
                 lambda leaf: jax.lax.dynamic_index_in_dim(
                     leaf, slot, keepdims=False), params)
             wo = (None if w_out is None else
                   jax.lax.dynamic_index_in_dim(w_out, slot, keepdims=False))
-            return _row_prefill(p, wo, cfg, h0_r, y0_r, u_r, yt_r, length,
-                                **kw)
-    else:
-        pooled = w_out is not None and w_out.ndim == 3
+            yt = None if yt_r is None else yt_r[None]
+            last, y_next, out = _rows_prefill(
+                p, wo, cfg, h0_r[None], y0_r[None], u_r[None], yt,
+                length[None], **kw)
+            return last[0], y_next[0], None if out is None else out[0]
 
-        def one(slot, h0_r, y0_r, u_r, yt_r, length):
-            # Shared reservoir, per-slot readout pool: row `slot` prefills
-            # against its own (F, D) readout sliced out of the (B, F, D) pool.
-            wo = (jax.lax.dynamic_index_in_dim(w_out, slot, keepdims=False)
-                  if pooled else w_out)
-            return _row_prefill(params, wo, cfg, h0_r, y0_r, u_r, yt_r,
-                                length, **kw)
-
-    if y_teacher is None:
-        last, y_next, out = jax.vmap(
-            lambda s, h, y, ur, ln: one(s, h, y, ur, None, ln))(
-                slots, h0, y0, u, lengths)
+        if y_teacher is None:
+            last, y_next, out = jax.vmap(
+                lambda s, h, y, ur, ln: one(s, h, y, ur, None, ln))(
+                    slots, h0, y0, u, lengths)
+        else:
+            last, y_next, out = jax.vmap(one)(slots, h0, y0, u, y_teacher,
+                                              lengths)
     else:
-        last, y_next, out = jax.vmap(one)(slots, h0, y0, u, y_teacher,
-                                          lengths)
+        # Shared reservoir: the whole wave is one (B_wave, T_bucket) scan.
+        # A per-slot readout pool (B, F, D) serves each row its own readout.
+        wo = w_out[slots] if w_out is not None and w_out.ndim == 3 else w_out
+        last, y_next, out = _rows_prefill(params, wo, cfg, h0, y0, u,
+                                          y_teacher, lengths, **kw)
     arena = dataclasses.replace(
         arena,
         states=arena.states.at[slots].set(last),

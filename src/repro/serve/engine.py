@@ -125,6 +125,12 @@ class ReservoirEngine:
     windows.  ``max_queued`` bounds the admission queue (:meth:`submit`
     raises :class:`AdmissionFull` beyond it).  The engine **snapshots
     (params, readout) at construction** — build it *after* fitting.
+
+    Precision: the engine serves in its params' dtype — float32 on the TPU
+    (x64 off), float64 in the CPU tests.  Arena, prefill scans, fused
+    decode and the readout all run in that dtype with every dot at full
+    precision (``core.dispatch.device_fn``); nothing runs below it.  Fit
+    the readout on the host in float64 (``core.esn.fit_host``).
     """
 
     def __init__(self, model, max_slots: int = 8, *,

@@ -148,9 +148,15 @@ class ExecPlane:
         self._arena_base = None
         self._base_valid = False
         self._base_dirty: set = set()
-        self._decode_jit = jax.jit(functools.partial(
+        # Every device function traces with full-precision dots (the engine
+        # serves in its params' dtype, float32 on the TPU, not below it) and
+        # with the arena's mesh in context (see core.dispatch.device_fn).
+        def on_device(fn):
+            return dispatch.device_fn(
+                fn, None if plan is None else plan.mesh)
+        self._decode_jit = jax.jit(on_device(functools.partial(
             arena_mod.decode_step, batched=self._batched,
-            ensemble=self.ensemble))
+            ensemble=self.ensemble)))
         # Closed-loop decode routes through the fused K-token path
         # (arena.closed_loop_fused -> core.dispatch.run_decode_fused): one
         # dispatch per wave instead of per token, Pallas kernel on TPU, jnp
@@ -166,16 +172,17 @@ class ExecPlane:
         # path is gated off and demotes fall back to the ordered gather.
         self._donate = bool(donate)
         self._closed_jit = jax.jit(
-            functools.partial(arena_mod.closed_loop_fused,
-                              batched=self._batched,
-                              ensemble=self.ensemble),
+            on_device(functools.partial(arena_mod.closed_loop_fused,
+                                        batched=self._batched,
+                                        ensemble=self.ensemble)),
             static_argnums=4, donate_argnums=donate)
         self._driven_jit = jax.jit(
-            functools.partial(arena_mod.driven_loop,
-                              batched=self._batched,
-                              ensemble=self.ensemble))
+            on_device(functools.partial(arena_mod.driven_loop,
+                                        batched=self._batched,
+                                        ensemble=self.ensemble)))
         self._wave_jit = jax.jit(
-            functools.partial(arena_mod.prefill_wave, batched=self._batched),
+            on_device(functools.partial(arena_mod.prefill_wave,
+                                        batched=self._batched)),
             static_argnames=("method", "chunk", "want_outputs"))
         # Paging bundles as ONE executable each: eagerly, place_many /
         # release_many / gather_rows cost several device dispatches per
@@ -284,7 +291,11 @@ class ExecPlane:
         1 - host_idle/wall."""
         e = self._inflight.popleft()
         t0 = time.perf_counter()
-        jax.block_until_ready(e["marker"])
+        # Under donation (TPU) a later dispatch may have consumed the
+        # marker's buffer.  That dispatch read it, so it runs after this
+        # wave, and the newer window entries cover it.
+        if not e["marker"].is_deleted():
+            jax.block_until_ready(e["marker"])
         self.tracker.log_wave({"kind": "host_block",
                                "us": (time.perf_counter() - t0) * 1e6})
         if self._base_valid:

@@ -32,6 +32,7 @@ import numpy as np
 
 from ..core import esn as esn_fn
 from ..core import ridge as ridge_mod
+from ..core.dispatch import device_fn
 from . import arena as arena_mod
 
 __all__ = ["LearnPlane", "_GramAcc", "_Member", "_LearnState"]
@@ -113,10 +114,11 @@ def _fold_rows_core(params, h, fb, y, g0, c0, lam):
 
 
 _fold_rows = functools.partial(jax.jit, static_argnames=("lam",))(
-    _fold_rows_core)
+    device_fn(_fold_rows_core))
 
 
 @functools.partial(jax.jit, static_argnames=("lam",))
+@device_fn
 def _fold_rows_batch(params, h, fb, y, g0, c0, lam):
     """The same fold vmapped over sessions (shared params): a refit wave
     whose dirty sessions share one window length — the steady serve
@@ -171,8 +173,8 @@ class LearnPlane:
         # (R, F, D) cross terms, (R, F, F) per-row metrics (EET
         # blockdiag(I, QᵀQ) for diag rows, identity for standard), shared
         # traced alpha.
-        self._refit_jit = jax.jit(jax.vmap(ridge_mod.ridge_solve_general,
-                                           in_axes=(0, 0, 0, None)))
+        self._refit_jit = jax.jit(device_fn(jax.vmap(
+            ridge_mod.ridge_solve_general, in_axes=(0, 0, 0, None))))
         # Facade-wired cross-plane callbacks (see class docstring).
         self.session_slot = lambda sid: None
         self.activate_pool = lambda: None

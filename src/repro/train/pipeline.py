@@ -20,8 +20,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import jax_compat
-
 
 def pipeline_apply(stage_fn, stage_params, x_micro, *, mesh, axis="pod"):
     """Run x through n_stages sequential stage_fns, pipelined over microbatches.
@@ -75,6 +73,6 @@ def pipeline_apply(stage_fn, stage_params, x_micro, *, mesh, axis="pod"):
     in_specs = (jax.tree.map(lambda _: P(axis), stage_params,
                              is_leaf=lambda v: hasattr(v, "shape")),
                 P())
-    return jax_compat.shard_map(
+    return jax.shard_map(
         shard_fn, mesh=mesh, in_specs=in_specs,
         out_specs=P(), check_vma=False)(stage_params, x_micro)

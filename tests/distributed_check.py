@@ -19,14 +19,14 @@ import numpy as np
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from repro import jax_compat
 from repro.configs import smoke_config
 from repro.models import blocks, lm
 from repro.models.blocks import NULL_PROFILE, ShardProfile
 
 assert jax.device_count() == 8, jax.device_count()
 
-mesh = jax_compat.make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 prof = ShardProfile(mesh=mesh, tp="model", fsdp=None, dp=("data",), tp_size=4)
 
 
@@ -122,7 +122,8 @@ print("[distributed_check] ALL OK", flush=True)
 # ------------------------------------------------- 4. pipeline parallelism
 from repro.train.pipeline import pipeline_apply
 
-mesh_pp = jax_compat.make_mesh((4, 2), ("pod", "model"))
+mesh_pp = jax.make_mesh((4, 2), ("pod", "model"),
+                        axis_types=(jax.sharding.AxisType.Auto,) * 2)
 rngk = jax.random.PRNGKey(7)
 n_stages, n_micro, mb, dd = 4, 6, 3, 16
 ws = jax.random.normal(rngk, (n_stages, dd, dd)) * 0.3
@@ -175,7 +176,7 @@ def shard_gram(x, y):
     return jax.lax.psum(g, "data"), jax.lax.psum(c, "data")
 
 
-g_d, c_d = jax_compat.shard_map(
+g_d, c_d = jax.shard_map(
     shard_gram, mesh=mesh, in_specs=(P("data", None), P("data", None)),
     out_specs=(P(), P()), check_vma=False)(xs, ys)
 check("ridge.gram_psum", g_d, g_full, tol=1e-5)

@@ -180,3 +180,29 @@ def test_parallel_state_collection_matches_sequential():
     chk = np.asarray(dia.run(u, method="chunked", chunk=32))
     np.testing.assert_allclose(ass, seq, rtol=1e-8, atol=1e-10)
     np.testing.assert_allclose(chk, seq, rtol=1e-8, atol=1e-10)
+
+
+@pytest.mark.parametrize("use_feedback", [False, True])
+@pytest.mark.parametrize("builder", ["dpg", "diag"])
+def test_fit_host_matches_device_fit(builder, use_feedback):
+    """The host float64 fit solves the same EET system as ``fit``: with a
+    1e-8 ridge the two solvers part ways only in the near-null space, so
+    their predictions after the washout agree and fit equally well."""
+    import dataclasses
+
+    from repro.core import esn as esn_fn
+    from repro.core.params import Readout
+    cfg = dataclasses.replace(CFG, use_feedback=use_feedback)
+    params = (esn_fn.dpg_params(cfg, "noisy_golden", sigma=0.1)
+              if builder == "dpg" else esn_fn.diag_params(cfg))
+    u, y = _xy(800)
+    yt = y if use_feedback else None
+    preds = []
+    for fit in (esn_fn.fit, esn_fn.fit_host):
+        w = fit(params, u, y, washout=100).w_out
+        assert w.dtype == params.dtype
+        preds.append(np.asarray(esn_fn.predict(params, Readout(w), u,
+                                               y_teacher=yt))[100:])
+    np.testing.assert_allclose(preds[1], preds[0], rtol=0, atol=1e-5)
+    rmse = [float(np.sqrt(np.mean((p - y[100:]) ** 2))) for p in preds]
+    assert rmse[1] <= 1.01 * rmse[0] + 1e-9

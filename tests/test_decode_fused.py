@@ -248,3 +248,41 @@ def test_decode_result_unifies_step_and_fused_paths():
     # drained: a second collect is empty
     again = eng.collect_decoded()
     assert len(again) == 0 and not again.waves
+
+
+@pytest.mark.parametrize("weights", ["shared", "per_slot", "mean"])
+def test_pallas_slot_tiles_match_ref(weights):
+    """20 slots span three 8-slot tiles of the kernel's grid: shared
+    weights (one constant block for every tile), per-slot weights
+    (slot-tiled blocks) and the mean ensemble (one tile) all match the jnp
+    reference."""
+    rng = np.random.default_rng(1)
+    b, nc, d = 20, 24, 2
+    lead = () if weights == "shared" else (b,)
+    mag = rng.uniform(0.3, 0.9, lead + (nc,))
+    ang = rng.uniform(0, np.pi, lead + (nc,))
+    args = (mag * np.cos(ang), mag * np.sin(ang),
+            rng.normal(0, 0.5, (b, nc)), rng.normal(0, 0.5, (b, nc)),
+            rng.normal(0, 0.5, (b, d)),
+            rng.normal(0, 0.3, lead + (d, nc)),
+            rng.normal(0, 0.3, lead + (d, nc)),
+            rng.normal(0, 0.1, lead + (d, d)), rng.normal(0, 0.1, lead + (d,)),
+            rng.normal(0, 0.1, lead + (nc, d)),
+            rng.normal(0, 0.1, lead + (nc, d)))
+    mask = rng.uniform(size=b) > 0.3
+    ensemble = "mean" if weights == "mean" else "off"
+    from repro.kernels import ops, ref
+    got = ops.decode_fused(*args, mask, k=5, ensemble=ensemble)
+    want = ref.decode_fused_ref(*args, mask, k=5, ensemble=ensemble)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), atol=1e-6)
+
+
+def test_pallas_refuses_64bit_lanes_on_tpu():
+    """A compiled (non-interpret) kernel call with float64 operands is a
+    configuration error, not a silent rounding to float32."""
+    from repro.kernels.diag_scan import decode_fused_pallas_raw
+    z = np.zeros((8, 128))
+    with pytest.raises(TypeError, match="32-bit lanes"):
+        decode_fused_pallas_raw(z[:1], z[:1], z, z, z, z, z, z[:, :128],
+                                z[:1], z, z, z, k=2, interpret=False)

@@ -1,15 +1,5 @@
 """Distributed-path equivalence, run in a subprocess with 8 placeholder
-devices (keeps the main pytest process at 1 device, per the assignment).
-
-Triage history: this suite was red from the seed onward.  Root cause — the
-mesh/shard_map call sites were written against the jax >= 0.5 API
-(``jax.sharding.AxisType`` + ``jax.make_mesh(axis_types=...)`` and
-``jax.shard_map(check_vma=...)``), neither of which exists in the pinned dev
-set's ``jax==0.4.37`` (there it is ``jax.experimental.shard_map.shard_map``
-with ``check_rep=``; mesh axes are implicitly Auto).  The fast lane never
-reaches a shard_map, so only this subprocess saw the AttributeError.  Fixed
-for real (no xfail) by routing every such call through
-``repro.jax_compat``, which feature-detects the spelling."""
+CPU devices (keeps the main pytest process at 1 device)."""
 import os
 import subprocess
 import sys
@@ -22,6 +12,7 @@ pytestmark = pytest.mark.slow  # subprocess + 8 placeholder devices; CI fast lan
 def test_distributed_equivalence():
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
     script = os.path.join(os.path.dirname(__file__), "distributed_check.py")
     out = subprocess.run([sys.executable, script], env=env,

@@ -191,6 +191,27 @@ def test_inflight_window_bounded_by_depth():
     assert eng.stats().pipeline_inflight == 0
 
 
+def test_donated_arena_retires_window_and_matches_sync(monkeypatch):
+    """The TPU engine donates the arena to the closed-loop decode, which
+    consumes the buffer a queued prefill wave left as its window marker:
+    retiring that wave must not block on the donated buffer, and the
+    outputs match the synchronous engine."""
+    params, readout, sig = _trained()
+    with monkeypatch.context() as m:        # the engine decides donation
+        m.setattr(jax, "default_backend", lambda: "tpu")     # at build time
+        pipe, sync = _pair(params, readout, max_slots=4)
+    assert pipe._donate and sync._donate
+    outs = []
+    for eng in (pipe, sync):
+        for i in range(4):
+            eng.submit(i, sig[10 * i:10 * i + 100, None])
+        eng.flush()
+        for _ in range(4):
+            eng.decode_closed_loop(2)
+        outs.append(eng.collect_decoded().tokens)
+    _assert_same_outputs(*outs)
+
+
 def test_inflight_window_bounded_by_predicted_slo_cost():
     """With a decode SLO set, the summed predicted cost of outstanding
     waves must fit it: a huge predicted wave cost forces depth-1 behavior

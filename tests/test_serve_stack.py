@@ -314,6 +314,7 @@ def test_sharded_arena_2x1_parity_subprocess():
     process keeps seeing 1 device."""
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
     script = os.path.join(os.path.dirname(__file__), "serve_sharded_check.py")
     out = subprocess.run([sys.executable, script], env=env,
