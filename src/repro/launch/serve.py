@@ -44,10 +44,12 @@ shutdown; ``ReservoirEngine.restore(PATH)`` resumes it bit-exactly.
 
 ``--tracker jsonl:PATH`` streams every serving event (prefill / decode /
 page / refit / frontend) to a replayable JSON-lines trace through the
-pluggable ``serve.telemetry.Tracker`` seam; ``--profile-dir DIR`` adds
-``jax.profiler`` capture windows around the waves.  The ``stats()``
-counters are derived from the same event stream, so trace and counters
-can never disagree.
+pluggable ``serve.telemetry.Tracker`` seam; the ``stats()`` counters are
+derived from the same event stream, so trace and counters can never
+disagree.  ``--profile-dir DIR`` runs the serving run under
+``jax.profiler.trace(DIR)``: one ``.xplane.pb`` holds the device's ops
+and the engine's ``serve.*`` host spans (planning, waves, dispatches,
+blocks on the device) on one clock.
 
 LM smoke loop (token-synchronous prefill + lock-step decode over the
 transformer/hybrid archs — KV/state caches):
@@ -62,6 +64,7 @@ sharding/rules.py).
 from __future__ import annotations
 
 import argparse
+import contextlib
 import time
 
 import jax
@@ -159,11 +162,9 @@ def serve_reservoir(args) -> None:
                      park_host_rows=args.park_host_rows,
                      cold_dir=args.cold_dir,
                      pipeline_depth=args.pipeline_depth,
-                     tracker=args.tracker, profile_dir=args.profile_dir)
-    if args.tracker or args.profile_dir:
-        sinks = [s for s in (args.tracker, args.profile_dir and
-                             f"profiler -> {args.profile_dir}") if s]
-        print(f"observability: {', '.join(sinks)} (stats() counters derive "
+                     tracker=args.tracker)
+    if args.tracker:
+        print(f"observability: {args.tracker} (stats() counters derive "
               f"from the same event stream)")
     if args.cold_dir and args.park_host_rows is None:
         raise SystemExit("--cold-dir needs --park-host-rows (the cold tier "
@@ -590,18 +591,20 @@ def main():
                          "the same event stream, so they can never "
                          "disagree with it)")
     ap.add_argument("--profile-dir", default=None, metavar="DIR",
-                    help="add jax.profiler capture windows around serving "
-                         "waves, written under DIR (composes with "
-                         "--tracker)")
+                    help="run the serving run under jax.profiler.trace(DIR): "
+                         "the device's ops and the serve.* host spans in "
+                         "one .xplane.pb under DIR")
     ap.add_argument("--snapshot", default=None, metavar="PATH",
                     help="serialize the whole engine on shutdown (arena + "
                          "parked-session table + scheduler queue + cost "
                          "model); ReservoirEngine.restore(PATH) resumes it")
     args = ap.parse_args()
-    if args.reservoir:
-        serve_reservoir(args)
-    else:
-        serve_lm(args)
+    with (jax.profiler.trace(args.profile_dir) if args.profile_dir
+          else contextlib.nullcontext()):
+        if args.reservoir:
+            serve_reservoir(args)
+        else:
+            serve_lm(args)
 
 
 if __name__ == "__main__":

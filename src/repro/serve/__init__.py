@@ -23,10 +23,11 @@ learn plane accumulates streaming eigenbasis ``(G, C)`` off the
 ``observe()`` teacher path, refits batched waves into per-tenant readout
 pools, and grows DPG ensembles on drift.
 ``telemetry`` — the pluggable ``Tracker`` protocol (``NullTracker`` /
-``JsonlTracker`` / ``ProfilerTracker`` / ``MultiTracker``, specs via
-``make_tracker``) every wave/page/refit/decode event flows through, and the
-``StatsAggregator`` that derives the ``stats()`` counters from that same
-stream.
+``JsonlTracker`` / ``MultiTracker``, specs via ``make_tracker``) every
+wave/page/refit/decode event flows through, the ``StatsAggregator`` that
+derives the ``stats()`` counters from that same stream, and ``span``: the
+``serve.*`` host spans a ``jax.profiler`` trace holds beside the device's
+ops.
 ``frontend``  — ``OpenLoopServer``: the asyncio open-loop front end on the
 ingest seam (per-token streaming queues, ``AdmissionFull`` backpressure,
 graceful drain); ``benchmarks/loadgen.py`` drives it at fixed offered load.
@@ -56,8 +57,7 @@ from .ingest import AdmissionFull
 from .scheduler import PrefillRequest, WaveItem, WaveScheduler, bucket_length
 from .store import HostPool, SessionStore
 from .telemetry import (JsonlTracker, MultiTracker, NullTracker,
-                        ProfilerTracker, StatsAggregator, Tracker,
-                        make_tracker)
+                        StatsAggregator, Tracker, make_tracker)
 
 __all__ = ["arena", "cost", "engine", "exec_plane", "frontend", "ingest",
            "learn", "scheduler", "store", "telemetry",
@@ -66,7 +66,7 @@ __all__ = ["arena", "cost", "engine", "exec_plane", "frontend", "ingest",
            "resolve_method", "run_scan_q",
            "DecodeResult", "EngineStats", "EvictResult", "ReservoirEngine",
            "SessionStats", "AdmissionFull",
-           "Tracker", "NullTracker", "JsonlTracker", "ProfilerTracker",
-           "MultiTracker", "StatsAggregator", "make_tracker",
+           "Tracker", "NullTracker", "JsonlTracker", "MultiTracker",
+           "StatsAggregator", "make_tracker",
            "PrefillRequest", "WaveItem", "WaveScheduler", "bucket_length",
            "HostPool", "SessionStore"]

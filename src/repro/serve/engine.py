@@ -31,8 +31,8 @@ from .ingest import AdmissionFull, IngestPlane, SessionStats, SessionTable
 from .learn import (LearnPlane, _GramAcc, _LearnState,  # noqa: F401
                     _Member)
 from .scheduler import WaveScheduler
-from .telemetry import (EngineStats, MultiTracker, ProfilerTracker,
-                        StatsAggregator, Tracker, make_tracker)
+from .telemetry import (EngineStats, MultiTracker, StatsAggregator, Tracker,
+                        make_tracker)
 
 __all__ = ["SessionStats", "DecodeResult", "EvictResult", "EngineStats",
            "AdmissionFull", "ReservoirEngine"]
@@ -121,10 +121,10 @@ class ReservoirEngine:
     interleaved flushes decode the most-urgent deadline first — premium
     sessions cannot be starved by default-tier traffic (pinned by test).
     ``tracker``: a ``serve.telemetry.Tracker`` or spec string (``"null"``,
-    ``"jsonl:PATH"``); ``profile_dir`` adds ``jax.profiler`` capture
-    windows.  ``max_queued`` bounds the admission queue (:meth:`submit`
-    raises :class:`AdmissionFull` beyond it).  The engine **snapshots
-    (params, readout) at construction** — build it *after* fitting.
+    ``"jsonl:PATH"``).  ``max_queued`` bounds the admission queue
+    (:meth:`submit` raises :class:`AdmissionFull` beyond it).  The engine
+    **snapshots (params, readout) at construction** — build it *after*
+    fitting.
 
     Precision: the engine serves in its params' dtype — float32 on the TPU
     (x64 off), float64 in the CPU tests.  Arena, prefill scans, fused
@@ -153,7 +153,6 @@ class ReservoirEngine:
                  growth_sigma: float = 0.1,
                  growth_washout: int = 64,
                  tracker=None,
-                 profile_dir: Optional[str] = None,
                  max_queued: Optional[int] = None,
                  _param_batch: bool = False):
         self.params, self.readout = _coerce_model(model, readout)
@@ -288,10 +287,8 @@ class ReservoirEngine:
         self._agg = StatsAggregator()
         if isinstance(tracker, Tracker):
             user: Optional[Tracker] = tracker
-            if profile_dir:
-                user = MultiTracker([user, ProfilerTracker(profile_dir)])
-        elif tracker is not None or profile_dir is not None:
-            user = make_tracker(tracker, profile_dir=profile_dir)
+        elif tracker is not None:
+            user = make_tracker(tracker)
         else:
             user = None
         self.tracker: Tracker = (MultiTracker([self._agg, user])
@@ -380,7 +377,6 @@ class ReservoirEngine:
                          park_host_rows: Optional[int] = None,
                          cold_dir: Optional[str] = None,
                          tracker=None,
-                         profile_dir: Optional[str] = None,
                          max_queued: Optional[int] = None
                          ) -> "ReservoirEngine":
         """Engine over a *batch* of independently-seeded reservoirs: slot
@@ -396,8 +392,7 @@ class ReservoirEngine:
                    decode_wave_tokens=decode_wave_tokens,
                    pipeline_depth=pipeline_depth,
                    park_host_rows=park_host_rows, cold_dir=cold_dir,
-                   tracker=tracker, profile_dir=profile_dir,
-                   max_queued=max_queued, _param_batch=True)
+                   tracker=tracker, max_queued=max_queued, _param_batch=True)
 
     # ------------------------------------------------- plane state (compat)
     # The facade owns NO serving state: every attribute below is a live

@@ -14,7 +14,11 @@ Control-plane state (session table, admission queue) and learn-plane
 effects (pairing counters, Gram snapshots, ensemble voting) reach this
 plane only through callbacks the facade wires at construction; every
 counter it used to bump in place is now an event emitted through the
-telemetry plane's ``Tracker`` seam.
+telemetry plane's ``Tracker`` seam.  Host time is marked by the
+telemetry plane's spans: ``serve.plan`` (the scheduler picks a wave),
+``serve.wave`` (one prefill wave), ``serve.dispatch`` (each jitted call on
+the serving path, attribute ``program``) and ``serve.block`` (each host
+wait on the device).
 """
 from __future__ import annotations
 
@@ -30,6 +34,7 @@ import numpy as np
 from ..core import dispatch
 from . import arena as arena_mod
 from .scheduler import WaveItem, bucket_length
+from .telemetry import span
 
 __all__ = ["ExecPlane", "DecodeResult", "EvictResult"]
 
@@ -295,7 +300,8 @@ class ExecPlane:
         # marker's buffer.  That dispatch read it, so it runs after this
         # wave, and the newer window entries cover it.
         if not e["marker"].is_deleted():
-            jax.block_until_ready(e["marker"])
+            with span("serve.block"):
+                jax.block_until_ready(e["marker"])
         self.tracker.log_wave({"kind": "host_block",
                                "us": (time.perf_counter() - t0) * 1e6})
         if self._base_valid:
@@ -578,15 +584,16 @@ class ExecPlane:
             # free-slot count still goes to the scheduler so the budget fit
             # can price the forced demote page wave (c_page of the
             # overflow) against the same decode SLO.
-            capacity = self._capacity(protect)
-            free = (self.table.free_slots if self.store is not None
-                    else None)
-            if not self.scheduler.has_runnable(capacity):
-                break
-            budget = (self._decode_budget(decode_sids)
-                      if decode_sids else None)
-            wave = self.scheduler.next_wave(capacity, budget_us=budget,
-                                            free_slots=free)
+            with span("serve.plan"):
+                capacity = self._capacity(protect)
+                free = (self.table.free_slots if self.store is not None
+                        else None)
+                if not self.scheduler.has_runnable(capacity):
+                    break
+                budget = (self._decode_budget(decode_sids)
+                          if decode_sids else None)
+                wave = self.scheduler.next_wave(capacity, budget_us=budget,
+                                                free_slots=free)
             if not wave:
                 if not just_decoded:
                     # Runnable prefill exists but is over the decode budget:
@@ -601,21 +608,26 @@ class ExecPlane:
                 # Fresh budget: waive the shrink-efficiency floor — a
                 # slow-but-SLO-compliant part-wave beats blowing the budget
                 # on the full one.
-                wave = self.scheduler.next_wave(
-                    capacity, budget_us=self._decode_budget(decode_sids),
-                    shrink_floor=0.0, free_slots=free)
-                if not wave:
-                    # Truly unsatisfiable: not even one row fits the SLO;
-                    # run unbudgeted rather than spin decode-only forever.
-                    wave = self.scheduler.next_wave(capacity,
-                                                    free_slots=free)
+                with span("serve.plan"):
+                    wave = self.scheduler.next_wave(
+                        capacity, budget_us=self._decode_budget(decode_sids),
+                        shrink_floor=0.0, free_slots=free)
                     if not wave:
-                        break
+                        # Truly unsatisfiable: not even one row fits the
+                        # SLO; run unbudgeted rather than spin decode-only
+                        # forever.
+                        wave = self.scheduler.next_wave(capacity,
+                                                        free_slots=free)
+                if not wave:
+                    break
             just_decoded = False
             waves_run += 1
             self._make_room(wave, protect)
-            self._run_wave(wave, capacity, results, method=method,
-                           chunk=chunk, want_outputs=want_outputs)
+            with span("serve.wave", rows=len(wave),
+                      t_bucket=lambda: self._bucket(wave),
+                      sids=lambda: [it.sid for it in wave if it.first]):
+                self._run_wave(wave, capacity, results, method=method,
+                               chunk=chunk, want_outputs=want_outputs)
             if (self.pipeline_depth > 0 and not self._autotune
                     and self.store is not None):
                 # Plan one wave ahead against *predicted* post-wave
@@ -627,7 +639,9 @@ class ExecPlane:
                 # draining the pipeline.  The next iteration's next_wave
                 # pops exactly this wave (peek is exact), and _make_room
                 # then finds the slots already free.
-                planned = self.scheduler.peek_wave(self._capacity(protect))
+                with span("serve.plan"):
+                    planned = self.scheduler.peek_wave(
+                        self._capacity(protect))
                 if planned:
                     self._make_room(planned, protect)
         if refit:
@@ -701,7 +715,8 @@ class ExecPlane:
         out = launch()
         us = None
         if t0 is not None:
-            jax.block_until_ready(out)
+            with span("serve.block"):
+                jax.block_until_ready(out)
             # ``out`` is downstream of every queued prefill wave (they share
             # the arena), so the whole in-flight window just materialized —
             # retire it without paying another block per entry.
@@ -746,9 +761,11 @@ class ExecPlane:
             st.last_use = self.table.tick()
 
         def launch():
-            self.arena, ys = self._closed_jit(
-                self.params, self._wave_w(), self.arena, jnp.asarray(mask),
-                int(self.decode_wave_tokens), self._ens_weights)
+            with span("serve.dispatch", program="closed_loop_fused"):
+                self.arena, ys = self._closed_jit(
+                    self.params, self._wave_w(), self.arena,
+                    jnp.asarray(mask), int(self.decode_wave_tokens),
+                    self._ens_weights)
             return ys
 
         ys = self._dispatch_decode(launch, sids,
@@ -785,9 +802,10 @@ class ExecPlane:
             st.last_use = self.table.tick()
 
         def launch():
-            self.arena, ys = self._driven_jit(
-                self.params, self._wave_w(), self.arena, jnp.asarray(mask),
-                jnp.asarray(u_seq), self._ens_weights)
+            with span("serve.dispatch", program="driven_loop"):
+                self.arena, ys = self._driven_jit(
+                    self.params, self._wave_w(), self.arena,
+                    jnp.asarray(mask), jnp.asarray(u_seq), self._ens_weights)
             return ys
 
         ys = self._dispatch_decode(launch, sids, tokens=k, block=True,
@@ -874,8 +892,10 @@ class ExecPlane:
                 slots.append(slot)
                 self.note_admission(it.sid, it.req.tenant)
             touched.update(slots)
-            self.arena = self._place_jit(self.arena, jnp.asarray(slots),
-                                         jnp.asarray(h0s), jnp.asarray(y0s))
+            with span("serve.dispatch", program="place_many"):
+                self.arena = self._place_jit(
+                    self.arena, jnp.asarray(slots), jnp.asarray(h0s),
+                    jnp.asarray(y0s))
             # Freshly placed slots must serve their tenant's pooled readout
             # from the first wave, not the engine-wide base.
             self.sync_slot_readouts(
@@ -887,12 +907,7 @@ class ExecPlane:
                 self._inflight_admit(self.arena.states, 1.0, touched,
                                      arena_before)
             return                  # admission-only wave (bucket 0)
-        # Max over the rows, not prompts[0]: a padded-up remainder chunk
-        # (scheduler mixed-kind waves) rides a wave whose bucket is set by
-        # its longest row; its own padded tail steps are inert.
-        t_bucket = max(bucket_length(it.length,
-                                     bucket_min=self.scheduler.bucket_min)
-                       for it in prompts)
+        t_bucket = self._bucket(prompts)
         bw = len(prompts)
         u_pad = np.zeros((bw, t_bucket, self.cfg.d_in), self._dtype)
         lengths = np.zeros((bw,), np.int32)
@@ -918,16 +933,18 @@ class ExecPlane:
             # be inflated by work that isn't this wave's.
             self._drain_inflight()
             t0 = time.perf_counter()
-        self.arena, out = self._wave_jit(
-            self.params, self._wave_w(), self.arena, slots,
-            jnp.asarray(u_pad), jnp.asarray(lengths),
-            None if yt_pad is None else jnp.asarray(yt_pad),
-            method=wave_method, chunk=chunk, want_outputs=want_outputs)
+        with span("serve.dispatch", program="prefill_wave"):
+            self.arena, out = self._wave_jit(
+                self.params, self._wave_w(), self.arena, slots,
+                jnp.asarray(u_pad), jnp.asarray(lengths),
+                None if yt_pad is None else jnp.asarray(yt_pad),
+                method=wave_method, chunk=chunk, want_outputs=want_outputs)
         us = None
         if t0 is not None:
             # Timing a wave means waiting for it — autotune trades a host
             # sync per wave for a cost model that tracks this machine.
-            jax.block_until_ready(self.arena.states)
+            with span("serve.block"):
+                jax.block_until_ready(self.arena.states)
             us = (time.perf_counter() - t0) * 1e6
             self.cost_model.observe(bw, t_bucket, us)
         elif self.pipeline_depth == 0:
@@ -935,7 +952,8 @@ class ExecPlane:
             # host plans the next one.  This is the reference the pipelined
             # path must stay bit-exact against.
             tb0 = time.perf_counter()
-            jax.block_until_ready(self.arena.states)
+            with span("serve.block"):
+                jax.block_until_ready(self.arena.states)
             self.tracker.log_wave({"kind": "host_block",
                                    "us": (time.perf_counter() - tb0) * 1e6})
         else:
@@ -982,6 +1000,16 @@ class ExecPlane:
                 else:
                     results[it.sid] = (chunks[0] if len(chunks) == 1
                                        else jnp.concatenate(chunks, axis=0))
+
+    def _bucket(self, items) -> int:
+        """The padded length of a wave of ``items``: the max over its rows
+        with a prompt, not the first row's — a padded-up remainder chunk
+        (scheduler mixed-kind waves) rides a wave whose bucket is set by its
+        longest row; its own padded tail steps are inert.  0 for an
+        admission-only wave."""
+        return max((bucket_length(it.length,
+                                  bucket_min=self.scheduler.bucket_min)
+                    for it in items if it.req.u is not None), default=0)
 
     def _record_wave(self, t_bucket: int, rows: int, fresh: int,
                      capacity: int, tokens: int,
@@ -1126,9 +1154,10 @@ class ExecPlane:
         self.note_steps(list(vecs))
 
         def launch():
-            self.arena, y = self._decode_jit(
-                self.params, self._wave_w(), self.arena, jnp.asarray(u),
-                jnp.asarray(mask), self._ens_weights)
+            with span("serve.dispatch", program="decode_step"):
+                self.arena, y = self._decode_jit(
+                    self.params, self._wave_w(), self.arena, jnp.asarray(u),
+                    jnp.asarray(mask), self._ens_weights)
             return y
 
         y = self._dispatch_decode(launch, list(vecs), tokens=1, block=False,
@@ -1202,9 +1231,10 @@ class ExecPlane:
             stats[sid].last_use = self.table.tick()
 
         def launch():
-            self.arena, ys = self._closed_jit(
-                self.params, self._wave_w(), self.arena, jnp.asarray(mask),
-                int(n_steps), self._ens_weights)
+            with span("serve.dispatch", program="closed_loop_fused"):
+                self.arena, ys = self._closed_jit(
+                    self.params, self._wave_w(), self.arena,
+                    jnp.asarray(mask), int(n_steps), self._ens_weights)
             return ys
 
         # Autotune times the dispatch (host sync, the price of a

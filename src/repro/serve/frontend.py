@@ -13,7 +13,11 @@ TTFT/inter-token SLO attainment.
 Everything here is host-side orchestration over the facade's public
 surface (``submit`` / ``queue_inputs`` / ``flush`` / ``collect_decoded`` /
 ``release``); no device work, no imports from the serving planes beyond
-the ingest exception type.  stdlib only.
+the ingest exception type and the telemetry plane's spans.  The loop's
+host time is marked by ``serve.*`` spans (``serve.cycle`` and, inside it,
+``serve.flush`` / ``serve.decode`` / ``serve.route``; ``serve.wait`` while
+nothing is runnable; ``serve.submit``), which a ``jax.profiler`` trace
+holds beside the device's ops.
 
 Typical use (see ``benchmarks/loadgen.py`` for the full loop)::
 
@@ -32,6 +36,7 @@ import time
 from typing import Dict, Hashable, List, Optional
 
 from .ingest import AdmissionFull
+from .telemetry import annotate, span
 
 __all__ = ["AdmissionFull", "OpenLoopServer", "StreamToken", "SessionHandle"]
 
@@ -168,8 +173,9 @@ class OpenLoopServer:
             raise KeyError(f"session {sid!r} already streaming")
         handle = SessionHandle(sid, n_decode)
         # May raise AdmissionFull/ValueError — nothing registered yet.
-        self.engine.submit(sid, u, y_teacher, h0=h0, y0=y0, tenant=tenant,
-                           decode_slo_us=decode_slo_us)
+        with span("serve.submit", sid=sid):
+            self.engine.submit(sid, u, y_teacher, h0=h0, y0=y0,
+                               tenant=tenant, decode_slo_us=decode_slo_us)
         handle.t_admitted = time.perf_counter()
         self._sessions[sid] = handle
         self._wake.set()
@@ -192,66 +198,85 @@ class OpenLoopServer:
     def _route_tokens(self) -> int:
         """Drain the engine's decode buffers into the per-session streams;
         close + release sessions that reached their quota."""
-        drained = self.engine.collect_decoded()
-        now = time.perf_counter()
-        routed = 0
-        for sid, arr in drained.tokens.items():
-            h = self._sessions.get(sid)
-            if h is None:
-                continue
-            for row in arr:
-                h._push(StreamToken(index=h.delivered, t_wall=now, y=row))
-                routed += 1
-        def _settled(sid):
-            # A session may only finish once its prompt fully landed —
-            # releasing a queued/chunk-in-flight sid would cancel it.
-            st = self.engine.sessions.get(sid)
-            if st is not None:
-                return not st.prefill_pending
-            return not self.engine.scheduler.has(sid)   # parked counts
-        finished = [sid for sid, h in self._sessions.items()
-                    if not h._closed and h.delivered >= h.n_decode
-                    and _settled(sid)]
-        for sid in finished:
-            h = self._sessions.pop(sid)
-            h._close()
-            self.engine.release(sid, drop=True)
-            self.engine.tracker.log_wave({
-                "kind": "frontend", "sid": sid, "tokens": h.n_decode,
-                "ttft_s": (None if h.t_first is None
-                           else h.t_first - h.t_submit),
-                "e2e_s": h.t_done - h.t_submit})
+        with span("serve.route") as route:
+            with span("serve.collect"):
+                drained = self.engine.collect_decoded()
+            # Token arrays the device has not finished when they are routed:
+            # their clients stamp them before the device's work is done.
+            annotate(route, unready=lambda: sum(
+                not arr.is_ready() for arr in drained.tokens.values()))
+            now = time.perf_counter()
+            routed = 0
+            for sid, arr in drained.tokens.items():
+                h = self._sessions.get(sid)
+                if h is None:
+                    continue
+                for row in arr:
+                    h._push(StreamToken(index=h.delivered, t_wall=now,
+                                        y=row))
+                    routed += 1
+
+            def _settled(sid):
+                # A session may only finish once its prompt fully landed —
+                # releasing a queued/chunk-in-flight sid would cancel it.
+                st = self.engine.sessions.get(sid)
+                if st is not None:
+                    return not st.prefill_pending
+                return not self.engine.scheduler.has(sid)  # parked counts
+            finished = [sid for sid, h in self._sessions.items()
+                        if not h._closed and h.delivered >= h.n_decode
+                        and _settled(sid)]
+            for sid in finished:
+                h = self._sessions.pop(sid)
+                h._close()
+                with span("serve.release", sid=sid):
+                    self.engine.release(sid, drop=True)
+                self.engine.tracker.log_wave({
+                    "kind": "frontend", "sid": sid, "tokens": h.n_decode,
+                    "ttft_s": (None if h.t_first is None
+                               else h.t_first - h.t_submit),
+                    "e2e_s": h.t_done - h.t_submit})
+            annotate(route, tokens=routed, sessions=len(drained.tokens))
         return routed
+
+    def _decode(self, want: List[Hashable]) -> None:
+        """The cycle's decode wave for the ready sessions in ``want``."""
+        eng = self.engine
+        with span("serve.decode", rows=len(want)) as decode:
+            k = min(int(getattr(eng, "decode_wave_tokens", 1) or 1),
+                    min(h.n_decode - h.delivered
+                        for h in (self._sessions[s] for s in want)))
+            annotate(decode, tokens=max(1, k))
+            driven = [s for s in want if eng._ingest.input_depth(s) > 0]
+            free = [s for s in want if s not in driven]
+            # Driven sessions advance through their queued open-loop
+            # inputs; free ones free-run closed-loop.
+            for s in driven:
+                rows = eng._ingest.pop_inputs(s, 1)
+                if rows:
+                    eng.decode_step({s: rows[0]})
+            if free:
+                eng.decode_closed_loop(max(1, k), sids=free)
 
     def _cycle(self) -> bool:
         """One serving iteration; returns whether any work ran."""
         eng = self.engine
-        worked = False
-        if len(eng.scheduler) > 0:
-            eng.flush(decode_interleave=self.decode_interleave,
-                      max_waves=self.max_waves_per_cycle)
-            worked = True
-        want = self._want_decode()
-        if want:
-            if self.decode_interleave and len(eng.scheduler) > 0:
-                pass        # interleaved flush above already decoded them
-            else:
-                k = min(int(getattr(eng, "decode_wave_tokens", 1) or 1),
-                        min(h.n_decode - h.delivered
-                            for h in (self._sessions[s] for s in want)))
-                driven = [s for s in want if eng._ingest.input_depth(s) > 0]
-                free = [s for s in want if s not in driven]
-                # Driven sessions advance through their queued open-loop
-                # inputs; free ones free-run closed-loop.
-                for s in driven:
-                    rows = eng._ingest.pop_inputs(s, 1)
-                    if rows:
-                        eng.decode_step({s: rows[0]})
-                if free:
-                    eng.decode_closed_loop(max(1, k), sids=free)
-            worked = True
-        if self._route_tokens() > 0:
-            worked = True
+        with span("serve.cycle", live=lambda: len(self._sessions),
+                  queued=lambda: len(eng.scheduler)):
+            worked = False
+            if len(eng.scheduler) > 0:
+                with span("serve.flush"):
+                    eng.flush(decode_interleave=self.decode_interleave,
+                              max_waves=self.max_waves_per_cycle)
+                worked = True
+            want = self._want_decode()
+            if want:
+                # An interleaved flush above already decoded them.
+                if not (self.decode_interleave and len(eng.scheduler) > 0):
+                    self._decode(want)
+                worked = True
+            if self._route_tokens() > 0:
+                worked = True
         return worked
 
     async def _serve(self) -> None:
@@ -264,8 +289,12 @@ class OpenLoopServer:
                 await asyncio.sleep(0)      # yield to submitters/consumers
             else:
                 self._wake.clear()
-                try:
-                    await asyncio.wait_for(self._wake.wait(),
-                                           timeout=self.idle_sleep_s)
-                except asyncio.TimeoutError:
-                    pass
+                # Nothing runnable: the one span held across an await;
+                # ``serve.submit`` spans of the requests that end the wait
+                # fall inside it.
+                with span("serve.wait"):
+                    try:
+                        await asyncio.wait_for(self._wake.wait(),
+                                               timeout=self.idle_sleep_s)
+                    except asyncio.TimeoutError:
+                        pass

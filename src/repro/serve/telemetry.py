@@ -1,46 +1,86 @@
 """Observability plane — the bottom of the serving-plane stack.
 
-Every wave / page / refit / decode event the other planes produce flows
-through ONE seam: a :class:`Tracker` with three methods —
-``log_wave(event)`` (a flat dict tagged by ``kind``), ``log_stats(stats)``
-(an :class:`EngineStats` or plain dict snapshot), and ``capture(name)``
-(a context manager wrapping a profiled region).  The engine's own serving
-counters are no longer ad-hoc ``self._stats[...]`` bumps: they are derived
-by :class:`StatsAggregator`, itself just another Tracker fed from the same
-event stream — so a JSONL trace and the ``stats()`` counters can never
-disagree about what happened.
+Two instruments, one seam each:
+
+* events: every wave / page / refit / decode event the other planes produce
+  flows through a :class:`Tracker` — ``log_wave(event)``, a flat dict
+  tagged by ``kind``.  The engine's own serving counters are no longer
+  ad-hoc ``self._stats[...]`` bumps: they are derived by
+  :class:`StatsAggregator`, itself just another Tracker fed from the same
+  event stream — so a JSONL trace and the ``stats()`` counters can never
+  disagree about what happened.
+* spans: :func:`span` marks where the serving loop's host time goes
+  (``serve.cycle``, ``serve.flush``, ``serve.dispatch``, ``serve.block``
+  ...) as ``jax.profiler.TraceAnnotation`` host events, so a profiler
+  trace (``jax.profiler.trace``, ``launch/serve.py --profile-dir``) holds
+  them on the same clock as the device's ops.  With no trace running a
+  span is one bare annotation and its attributes are never built.
 
 Layering: this module imports NOTHING from the rest of ``repro.serve``
-(enforced by tests/test_serving_planes.py).  ``jax`` is imported lazily
-and only by :class:`ProfilerTracker`.
+(enforced by tests/test_serving_planes.py).
 
 Trackers:
 
 * :class:`NullTracker`   — the default; every hook is a no-op.
-* :class:`JsonlTracker`  — appends one JSON object per event/stats call.
-* :class:`ProfilerTracker` — ``capture(name)`` opens a ``jax.profiler``
-  trace window under its directory (levanter Performance-Guide pattern).
+* :class:`JsonlTracker`  — appends one JSON object per event.
 * :class:`MultiTracker`  — fan-out to several trackers.
 * :func:`make_tracker`   — CLI spec parser (``"null"``, ``"jsonl:PATH"``).
 """
 from __future__ import annotations
 
 import collections
-import contextlib
 import dataclasses
 import json
 import time
 from typing import Dict, Hashable, List, Optional
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
-__all__ = ["Tracker", "NullTracker", "JsonlTracker", "ProfilerTracker",
-           "MultiTracker", "StatsAggregator", "EngineStats", "make_tracker"]
+__all__ = ["Tracker", "NullTracker", "JsonlTracker", "MultiTracker",
+           "StatsAggregator", "EngineStats", "make_tracker", "span",
+           "annotate"]
+
+
+def _attr(value):
+    """One span attribute as the trace can hold it.  A callable is called
+    (an attribute that costs work to build); a collection becomes one
+    ``|``-joined string.  The trace's metadata encoding ends a value at
+    ``,`` and ``#``, so those become ``;`` in strings."""
+    if callable(value):
+        value = value()
+    if isinstance(value, (bool, int, float)):
+        return value
+    if isinstance(value, (list, tuple, set, frozenset)):
+        value = "|".join(map(str, value))
+    return str(value).replace(",", ";").replace("#", ";")
+
+
+def span(name: str, **attrs):
+    """Context manager: a host span ``name`` on the profiler's trace.
+
+    Attributes are built only while a trace is being taken
+    (``TraceAnnotation.is_enabled()``); otherwise this is one bare
+    annotation, well under a microsecond.  Pass an attribute that costs
+    work to build as a zero-argument callable.  Spans nest: a span's self
+    time is its duration minus the time its children cover."""
+    if attrs and TraceAnnotation.is_enabled():
+        return TraceAnnotation(name, **{k: _attr(v)
+                                        for k, v in attrs.items()})
+    return TraceAnnotation(name)
+
+
+def annotate(open_span, **attrs) -> None:
+    """Add attributes known only inside ``open_span`` (the entered
+    :func:`span`); a no-op while no trace is being taken."""
+    if TraceAnnotation.is_enabled():
+        open_span.set_metadata(**{k: _attr(v) for k, v in attrs.items()})
 
 
 class Tracker:
-    """The pluggable observability protocol.  Subclass and override any of
-    the three hooks; the base class is a valid no-op tracker."""
+    """The pluggable event protocol.  Subclass and override ``log_wave``
+    (and ``close`` for a sink that holds a resource); the base class is a
+    valid no-op tracker."""
 
     def log_wave(self, event: dict) -> None:
         """One serving event — a flat dict carrying ``kind`` (``prefill`` /
@@ -48,28 +88,12 @@ class Tracker:
         ``host_block`` / ``overlap_demote`` / ``admit`` / ``release`` /
         ``frontend``...) plus kind-specific fields."""
 
-    def log_stats(self, stats) -> None:
-        """A periodic engine ``stats()`` snapshot (EngineStats or dict)."""
-
-    def capture(self, name: str):
-        """Context manager around a region worth profiling.  The base
-        implementation is a no-op window."""
-        return contextlib.nullcontext()
-
     def close(self) -> None:
         """Flush and release any underlying sink."""
 
 
 class NullTracker(Tracker):
     """Explicitly-named no-op tracker (the engine default)."""
-
-
-def _jsonable(obj):
-    if isinstance(obj, EngineStats):
-        return obj.to_dict()
-    if dataclasses.is_dataclass(obj) and not isinstance(obj, type):
-        return dataclasses.asdict(obj)
-    return obj
 
 
 def _default(obj):
@@ -85,9 +109,9 @@ def _default(obj):
 
 
 class JsonlTracker(Tracker):
-    """Append-only JSON-lines sink: one object per ``log_wave`` /
-    ``log_stats`` call, each stamped with a wall-clock ``t`` — the trace
-    artifact CI benches attach to perf regressions."""
+    """Append-only JSON-lines sink: one object per ``log_wave`` call, each
+    stamped with a wall-clock ``t`` — the trace artifact CI benches attach
+    to perf regressions."""
 
     def __init__(self, path: str):
         self.path = str(path)
@@ -99,38 +123,10 @@ class JsonlTracker(Tracker):
     def log_wave(self, event: dict) -> None:
         self._emit({"t": time.time(), "type": "wave", **event})
 
-    def log_stats(self, stats) -> None:
-        self._emit({"t": time.time(), "type": "stats",
-                    "stats": _jsonable(stats)})
-
-    def capture(self, name: str):
-        self._emit({"t": time.time(), "type": "capture", "name": name})
-        return contextlib.nullcontext()
-
     def close(self) -> None:
         if not self._fh.closed:
             self._fh.flush()
             self._fh.close()
-
-
-class ProfilerTracker(Tracker):
-    """``capture(name)`` wraps the region in a ``jax.profiler`` trace
-    written under ``profile_dir`` — so a regression report can carry a
-    device trace, not just a number.  Event/stats hooks are no-ops (pair
-    with a :class:`JsonlTracker` through :class:`MultiTracker`)."""
-
-    def __init__(self, profile_dir: str):
-        self.profile_dir = str(profile_dir)
-
-    @contextlib.contextmanager
-    def _window(self, name: str):
-        import jax
-        with jax.profiler.trace(self.profile_dir):
-            with jax.profiler.TraceAnnotation(name):
-                yield
-
-    def capture(self, name: str):
-        return self._window(name)
 
 
 class MultiTracker(Tracker):
@@ -144,42 +140,20 @@ class MultiTracker(Tracker):
         for t in self.trackers:
             t.log_wave(event)
 
-    def log_stats(self, stats) -> None:
-        for t in self.trackers:
-            t.log_stats(stats)
-
-    def capture(self, name: str):
-        with contextlib.ExitStack() as stack:
-            for t in self.trackers:
-                stack.enter_context(t.capture(name))
-            detached = stack.pop_all()
-        return detached
-
     def close(self) -> None:
         for t in self.trackers:
             t.close()
 
 
-def make_tracker(spec: Optional[str] = None,
-                 profile_dir: Optional[str] = None) -> Tracker:
+def make_tracker(spec: Optional[str] = None) -> Tracker:
     """Build a tracker from a CLI spec: ``None``/``"null"`` -> no-op,
-    ``"jsonl:PATH"`` -> :class:`JsonlTracker`.  ``profile_dir`` adds a
-    :class:`ProfilerTracker` capture window on top (MultiTracker)."""
-    trackers: List[Tracker] = []
-    if spec and spec != "null":
-        if spec.startswith("jsonl:"):
-            trackers.append(JsonlTracker(spec[len("jsonl:"):]))
-        else:
-            raise ValueError(
-                f"unknown tracker spec {spec!r} — expected 'null' or "
-                f"'jsonl:PATH'")
-    if profile_dir:
-        trackers.append(ProfilerTracker(profile_dir))
-    if not trackers:
+    ``"jsonl:PATH"`` -> :class:`JsonlTracker`."""
+    if not spec or spec == "null":
         return NullTracker()
-    if len(trackers) == 1:
-        return trackers[0]
-    return MultiTracker(trackers)
+    if spec.startswith("jsonl:"):
+        return JsonlTracker(spec[len("jsonl:"):])
+    raise ValueError(f"unknown tracker spec {spec!r} — expected 'null' or "
+                     f"'jsonl:PATH'")
 
 
 class StatsAggregator(Tracker):
