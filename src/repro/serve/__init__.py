@@ -18,10 +18,11 @@ data, ``learn`` learn-while-serving — one-way imports, enforced by test).
 The facade holds the public submit/flush/decode/release lifecycle, wires
 the cross-plane callbacks, and merges the planes' snapshots into the typed
 ``EngineStats``.  Decode tokens drain through ``collect_decoded()`` as one
-typed ``DecodeResult`` whatever path produced them; with ``learn=True`` the
-learn plane accumulates streaming eigenbasis ``(G, C)`` off the
-``observe()`` teacher path, refits batched waves into per-tenant readout
-pools, and grows DPG ensembles on drift.
+typed ``DecodeResult`` of host numpy arrays whatever path produced them
+(each decode wave's output crosses to the host in one copy, started at
+dispatch); with ``learn=True`` the learn plane accumulates streaming
+eigenbasis ``(G, C)`` off the ``observe()`` teacher path, refits batched
+waves into per-tenant readout pools, and grows DPG ensembles on drift.
 ``telemetry`` — the pluggable ``Tracker`` protocol (``NullTracker`` /
 ``JsonlTracker`` / ``MultiTracker``, specs via ``make_tracker``) every
 wave/page/refit/decode event flows through, the ``StatsAggregator`` that

@@ -22,6 +22,7 @@ wait on the device).
 """
 from __future__ import annotations
 
+import collections.abc
 import dataclasses
 import functools
 import time
@@ -45,17 +46,25 @@ class DecodeResult:
     returns for single-step, interleaved, driven, and fused K-token decode
     alike.
 
-    ``tokens``: sid -> (n_tokens, D_out) array — every decode path buffers in
-    this shape, so a caller never branches on where a token came from.
+    ``tokens``: sid -> (n_tokens, D_out) host numpy array — every decode path
+    buffers in this shape, so a caller never branches on where a token came
+    from.  A decode wave's output crosses to the host in ONE copy, started
+    at dispatch and made by the first drain that needs it; each session's
+    rows are numpy slices of that copy.
     ``waves``: per-dispatch metadata dicts (``kind`` "step" / "closed_loop" /
     "interleave" / "driven", ``rows``, ``tokens`` per row, ``us`` wall time
     when timed, ``fused`` whether the K-token fused kernel ran) for the
-    dispatches whose tokens this result drained.  Mapping-shaped on
-    ``tokens`` (iter / ``[]`` / ``items`` / ``get``), so dict-era callers
-    keep working unchanged.
+    dispatches whose tokens this result drained.  ``pulls``: the host copies
+    this drain made; ``waited``: how many of them found the wave's output
+    not yet computed when the drain began; ``unready``: the sessions with a
+    token not yet computed then.  Mapping-shaped on ``tokens`` (iter / ``[]``
+    / ``items`` / ``get``), so dict-era callers keep working unchanged.
     """
-    tokens: Dict[Hashable, jnp.ndarray]
+    tokens: Dict[Hashable, np.ndarray]
     waves: Tuple[dict, ...] = ()
+    pulls: int = 0
+    waited: int = 0
+    unready: int = 0
 
     def __getitem__(self, sid):
         return self.tokens[sid]
@@ -80,6 +89,71 @@ class DecodeResult:
 
     def get(self, sid, default=None):
         return self.tokens.get(sid, default)
+
+
+class _Wave:
+    """One decode dispatch's output ``ys`` (K, max_slots, D_out) on its way
+    to the host.  The device-to-host copy starts at dispatch
+    (``copy_to_host_async``), so the serving loop never waits for it there;
+    :meth:`host` turns it into a numpy array once, whichever of the wave's
+    sessions is drained first, and every session's rows are slices of it."""
+    __slots__ = ("_ys", "_host")
+
+    def __init__(self, ys):
+        ys.copy_to_host_async()
+        self._ys = ys
+        self._host = None
+
+    @property
+    def pulled(self) -> bool:
+        return self._host is not None
+
+    def ready(self) -> bool:
+        """Whether the output is computed (or already on the host)."""
+        return self._host is not None or self._ys.is_ready()
+
+    def host(self) -> np.ndarray:
+        if self._host is None:
+            with span("serve.block"):
+                self._host = np.asarray(self._ys)
+            self._ys = None
+        return self._host
+
+
+class _Rows:
+    """One session's column of a decode wave, as the decode buffers hold it:
+    ``np.asarray`` reads it from the wave's one host copy."""
+    __slots__ = ("wave", "slot")
+
+    def __init__(self, wave: _Wave, slot: int):
+        self.wave = wave
+        self.slot = slot
+
+    def __array__(self, dtype=None, copy=None):
+        rows = self.wave.host()[:, self.slot]
+        if dtype is not None:
+            rows = rows.astype(dtype)
+        return rows.copy() if copy else rows
+
+
+class _WaveTokens(collections.abc.Mapping):
+    """What :meth:`ExecPlane.decode_closed_loop` returns: sid -> the wave's
+    (n_steps, D_out) host rows, read from the wave's one host copy on first
+    access — a caller that drains through ``collect_decoded`` alone never
+    waits on the device here."""
+    __slots__ = ("_rows",)
+
+    def __init__(self, rows: Dict[Hashable, _Rows]):
+        self._rows = rows
+
+    def __getitem__(self, sid) -> np.ndarray:
+        return np.asarray(self._rows[sid])
+
+    def __iter__(self):
+        return iter(self._rows)
+
+    def __len__(self) -> int:
+        return len(self._rows)
 
 
 class EvictResult(tuple):
@@ -149,7 +223,7 @@ class ExecPlane:
         # it without waiting for the in-flight scans (see _demote_wave);
         # ``_base_valid`` drops to False whenever an untracked path mutates
         # the arena while waves are in flight.
-        self._inflight = __import__("collections").deque()
+        self._inflight = collections.deque()
         self._arena_base = None
         self._base_valid = False
         self._base_dirty: set = set()
@@ -763,9 +837,8 @@ class ExecPlane:
         def launch():
             with span("serve.dispatch", program="closed_loop_fused"):
                 self.arena, ys = self._closed_jit(
-                    self.params, self._wave_w(), self.arena,
-                    jnp.asarray(mask), int(self.decode_wave_tokens),
-                    self._ens_weights)
+                    self.params, self._wave_w(), self.arena, mask,
+                    int(self.decode_wave_tokens), self._ens_weights)
             return ys
 
         ys = self._dispatch_decode(launch, sids,
@@ -773,9 +846,7 @@ class ExecPlane:
                                    block=True, interleave=True,
                                    kind="interleave")
         self.note_freerun(sids, self.decode_wave_tokens)
-        for sid in sids:
-            self._decode_buf.setdefault(sid, []).append(
-                ys[:, self.table.sessions[sid].slot])
+        self._buffer_wave(ys, sids)
 
     def _driven_wave(self, sids: List) -> None:
         """One interleaved *teacher-driven* decode wave: drain up to
@@ -804,26 +875,38 @@ class ExecPlane:
         def launch():
             with span("serve.dispatch", program="driven_loop"):
                 self.arena, ys = self._driven_jit(
-                    self.params, self._wave_w(), self.arena,
-                    jnp.asarray(mask), jnp.asarray(u_seq), self._ens_weights)
+                    self.params, self._wave_w(), self.arena, mask, u_seq,
+                    self._ens_weights)
             return ys
 
         ys = self._dispatch_decode(launch, sids, tokens=k, block=True,
                                    interleave=True, kind="driven")
         self.note_freerun(sids, k)
-        for sid in sids:
-            self._decode_buf.setdefault(sid, []).append(
-                ys[:, self.table.sessions[sid].slot])
+        self._buffer_wave(ys, sids)
+
+    def _buffer_wave(self, ys, sids) -> Dict[Hashable, _Rows]:
+        """Buffer one decode wave's output for ``collect_decoded``: a handle
+        on the whole of ``ys`` (its host copy starts now) and each
+        session's slot, never a per-session device slice."""
+        if not sids:
+            return {}
+        wave = _Wave(ys)
+        rows = {sid: _Rows(wave, self.table.sessions[sid].slot)
+                for sid in sids}
+        for sid, r in rows.items():
+            self._decode_buf.setdefault(sid, []).append(r)
+        return rows
 
     def collect_decoded(self, sid: Optional[Hashable] = None) -> DecodeResult:
-        """Drain the decoded tokens every decode path buffered (see the
-        facade docstring).  Buffers clear on read."""
+        """Drain the decoded tokens every decode path buffered, as one
+        :class:`DecodeResult` of host numpy arrays (``sid`` -> its
+        ``(n_tokens, D_out)`` rows, in decode order).  Each decode wave is
+        copied to the host once, by the first drain that needs it; a drain
+        waits on the device only for a wave not yet computed (``waited``).
+        With ``sid``, drains that session alone (an empty array when
+        nothing is buffered).  Buffers clear on read."""
         if sid is not None:
-            chunks = self._decode_buf.pop(sid, [])
-            arr = (jnp.zeros((0, self.cfg.d_out), self._dtype)
-                   if not chunks else
-                   chunks[0] if len(chunks) == 1
-                   else jnp.concatenate(chunks, axis=0))
+            bufs = {sid: self._decode_buf.pop(sid, [])}
             waves = []
             for meta in list(self._decode_meta):
                 pending = meta["_pending"]
@@ -833,14 +916,33 @@ class ExecPlane:
                     pending.discard(sid)
                     if not pending:
                         self._decode_meta.remove(meta)
-            return DecodeResult(tokens={sid: arr}, waves=tuple(waves))
-        out = {s: (c[0] if len(c) == 1 else jnp.concatenate(c, axis=0))
-               for s, c in self._decode_buf.items()}
-        self._decode_buf.clear()
-        waves = tuple({k: v for k, v in meta.items() if k != "_pending"}
-                      for meta in self._decode_meta)
-        self._decode_meta.clear()
-        return DecodeResult(tokens=out, waves=waves)
+        else:
+            bufs = dict(self._decode_buf)
+            self._decode_buf.clear()
+            waves = [{k: v for k, v in meta.items() if k != "_pending"}
+                     for meta in self._decode_meta]
+            self._decode_meta.clear()
+        # Which waves still have to cross, and whether each is computed,
+        # before any of them is pulled.
+        ready: Dict[_Wave, bool] = {}
+        unready = 0
+        for chunks in bufs.values():
+            late = False
+            for c in chunks:
+                if isinstance(c, _Rows) and not c.wave.pulled:
+                    if c.wave not in ready:
+                        ready[c.wave] = c.wave.ready()
+                    late = late or not ready[c.wave]
+            unready += late
+        tokens = {s: (np.zeros((0, self.cfg.d_out), self._dtype)
+                      if not c else np.asarray(c[0]) if len(c) == 1
+                      else np.concatenate([np.asarray(x) for x in c],
+                                          axis=0))
+                  for s, c in bufs.items()}
+        return DecodeResult(tokens=tokens, waves=tuple(waves),
+                            pulls=len(ready),
+                            waited=sum(not r for r in ready.values()),
+                            unready=unready)
 
     def _note_decode(self, sids, *, us=None, tokens: int = 1,
                      interleave: bool = False,
@@ -1156,8 +1258,8 @@ class ExecPlane:
         def launch():
             with span("serve.dispatch", program="decode_step"):
                 self.arena, y = self._decode_jit(
-                    self.params, self._wave_w(), self.arena, jnp.asarray(u),
-                    jnp.asarray(mask), self._ens_weights)
+                    self.params, self._wave_w(), self.arena, u, mask,
+                    self._ens_weights)
             return y
 
         y = self._dispatch_decode(launch, list(vecs), tokens=1, block=False,
@@ -1181,7 +1283,7 @@ class ExecPlane:
             # Unified decode surface: single steps buffer as (1, D) rows so
             # collect_decoded() drains every path the same way.
             self._decode_buf.setdefault(sid, []).append(
-                jnp.asarray(row)[None])
+                np.asarray(row)[None])
         return out
 
     def observe(self, sid: Hashable, y_true):
@@ -1211,7 +1313,12 @@ class ExecPlane:
         self.arena = arena_mod.force_output(self.arena, st.slot, y)
 
     def decode_closed_loop(self, n_steps: int, sids=None):
-        """The free-running generation body (see the facade docstring)."""
+        """Free-run ``n_steps`` closed-loop tokens for ``sids`` (default:
+        every ready session) in one fused dispatch.  The tokens buffer for
+        :meth:`collect_decoded`; the return maps each sid to its
+        ``(n_steps, D_out)`` host rows, read from the wave's one host copy
+        on first access, so a loop that drains through ``collect_decoded``
+        stays asynchronous here."""
         if self.readout is None:
             raise ValueError("closed-loop decode needs a trained readout")
         if self.cfg.d_in != self.cfg.d_out:
@@ -1233,8 +1340,8 @@ class ExecPlane:
         def launch():
             with span("serve.dispatch", program="closed_loop_fused"):
                 self.arena, ys = self._closed_jit(
-                    self.params, self._wave_w(), self.arena,
-                    jnp.asarray(mask), int(n_steps), self._ens_weights)
+                    self.params, self._wave_w(), self.arena, mask,
+                    int(n_steps), self._ens_weights)
             return ys
 
         # Autotune times the dispatch (host sync, the price of a
@@ -1244,10 +1351,7 @@ class ExecPlane:
                                    block=False,
                                    slots=[stats[s].slot for s in targets])
         self.note_freerun(targets, n_steps)
-        # ys: (n_steps, max_slots, d_out) — return lazy device slices so
-        # callers (pipelined serving loops) stay async; convert to host
-        # memory on their own schedule (autotune forces the sync above).
-        out = {sid: ys[:, stats[sid].slot] for sid in targets}
-        for sid, arr in out.items():
-            self._decode_buf.setdefault(sid, []).append(arr)
-        return out
+        # ys: (n_steps, max_slots, d_out).  Nothing waits on the device
+        # here: the wave's host copy starts now and is read once, when its
+        # tokens are drained (or the returned mapping is indexed).
+        return _WaveTokens(self._buffer_wave(ys, targets))

@@ -199,12 +199,13 @@ class OpenLoopServer:
         """Drain the engine's decode buffers into the per-session streams;
         close + release sessions that reached their quota."""
         with span("serve.route") as route:
-            with span("serve.collect"):
+            with span("serve.collect") as collect:
                 drained = self.engine.collect_decoded()
-            # Token arrays the device has not finished when they are routed:
-            # their clients stamp them before the device's work is done.
-            annotate(route, unready=lambda: sum(
-                not arr.is_ready() for arr in drained.tokens.values()))
+                annotate(collect, pulls=drained.pulls, waited=drained.waited)
+            # The tokens are on the host now; ``unready`` counts the
+            # sessions whose tokens the device had not computed when the
+            # drain began (the drain waited for them).
+            annotate(route, unready=drained.unready)
             now = time.perf_counter()
             routed = 0
             for sid, arr in drained.tokens.items():

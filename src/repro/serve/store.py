@@ -749,8 +749,7 @@ def restore_engine(cls, path: str, *, mesh=None):
         eng.scheduler._deferred = _sid_from_json(m["deferred"])
 
     for i, rec in enumerate(m["decode_buf"]):
-        eng._decode_buf[_sid_from_json(rec["sid"])] = [
-            jnp.asarray(data[f"dec{i}"])]
+        eng._decode_buf[_sid_from_json(rec["sid"])] = [data[f"dec{i}"]]
     for i, rec in enumerate(m["chunk_outs"]):
         eng._chunk_outs[_sid_from_json(rec["sid"])] = [
             jnp.asarray(data[f"chunk{i}"])]

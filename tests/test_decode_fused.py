@@ -17,10 +17,12 @@ to thread K-token waves through the whole serving stack:
 * the reference backend and the Pallas kernel (interpret mode off-TPU)
   agree, including for feedback models where the two drive matmuls fold
   into one ``win_q + wfb_q``;
-* every decode path drains through one typed :class:`DecodeResult`.
+* every decode path drains through one typed :class:`DecodeResult` of host
+  numpy arrays, each wave copied to the host once.
 """
 import dataclasses
 
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -136,7 +138,6 @@ def _batched_trained(n_members=3):
             batch.append(p)
     assert len(batch) == n_members
     params = stack_params(batch)
-    import jax.numpy as jnp
     from repro.core.params import Readout
     readout = Readout(jnp.stack([
         esn_fn.fit(p, sig[:-1, None], sig[1:, None], washout=50).w_out
@@ -286,3 +287,130 @@ def test_pallas_refuses_64bit_lanes_on_tpu():
     with pytest.raises(TypeError, match="32-bit lanes"):
         decode_fused_pallas_raw(z[:1], z[:1], z, z, z, z, z, z[:, :128],
                                 z[:1], z, z, z, k=2, interpret=False)
+
+
+# ----------------------------------------------- one host copy per wave
+def _capture(eng, name):
+    """Record every token output of the engine's jitted ``name`` (the
+    kernel's whole ``ys``, or a single step's ``(max_slots, D)`` rows)."""
+    outs = []
+    fn = getattr(eng._exec, name)
+
+    def call(*args, **kw):
+        arena, ys = fn(*args, **kw)
+        outs.append(ys)
+        return arena, ys
+    setattr(eng._exec, name, call)
+    return outs
+
+
+def _no_device_indexing(monkeypatch):
+    """Make indexing or iterating a device array raise: the per-session
+    slices and per-token rows the host copy replaces."""
+    def boom(*args, **kw):
+        raise AssertionError("device array sliced on the host")
+    array_type = type(jnp.zeros(()))
+    monkeypatch.setattr(array_type, "__getitem__", boom)
+    monkeypatch.setattr(array_type, "__iter__", boom)
+
+
+def _decode_by(path, params, readout, sig, sids):
+    """Run one decode of ``path`` on a fresh engine; return the engine and
+    sid -> the host rows the kernel emitted for that session."""
+    if path in ("batched", "mean"):
+        bparams, breadout, _ = _batched_trained()
+        eng = ReservoirEngine.from_param_batch(
+            bparams, readout=breadout,
+            ensemble="mean" if path == "mean" else "off")
+        sids = list(range(eng.max_slots))
+        for i in sids:
+            eng.submit(i, sig[600 + i:700 + i, None])
+        eng.flush()
+    else:
+        eng = _engine(params, readout, sig, sids, decode_wave_tokens=3)
+    name = {"driven": "_driven_jit", "step": "_decode_jit"}.get(
+        path, "_closed_jit")
+    outs = _capture(eng, name)
+    if path in ("closed_loop", "batched", "mean"):
+        eng.decode_closed_loop(4)
+    elif path == "interleave":
+        eng._decode_wave(sids)
+    elif path == "driven":
+        for i, s in enumerate(sids):
+            eng.queue_inputs(s, sig[700 + i:705 + i, None])
+        eng._driven_wave(sids)
+    else:
+        eng.decode_step({s: sig[700 + i, None] for i, s in enumerate(sids)})
+    (ys,) = outs
+    ys = np.asarray(ys)
+    if path == "step":
+        ys = ys[None]
+    return eng, {s: ys[:, eng.sessions[s].slot] for s in sids}
+
+
+@pytest.mark.parametrize("path", ["closed_loop", "interleave", "driven",
+                                  "step", "batched", "mean"])
+def test_every_decode_path_drains_host_columns_of_the_wave(path,
+                                                           monkeypatch):
+    """Whatever path decoded them, the drained tokens are host numpy arrays
+    equal bit for bit to the session's column of the kernel's output, and
+    the drain indexes no device array."""
+    params, readout, sig = _trained()
+    eng, want = _decode_by(path, params, readout, sig, ["a", "b", "c"])
+    _no_device_indexing(monkeypatch)
+    res = eng.collect_decoded()
+    assert set(res.tokens) == set(want)
+    for s, rows in want.items():
+        assert isinstance(res[s], np.ndarray)
+        np.testing.assert_array_equal(res[s], rows)
+    assert res.pulls == (0 if path == "step" else 1)
+
+
+def test_one_sid_drain_and_release_match_the_full_drain():
+    """Draining session by session, or through ``release``, returns the
+    same rows as one drain of every session."""
+    params, readout, sig = _trained()
+    sids = ["a", "b", "c"]
+    engines = [_engine(params, readout, sig, sids) for _ in range(2)]
+    for eng in engines:
+        eng.decode_closed_loop(3)
+        eng.decode_step({s: sig[700, None] for s in sids})
+        eng.decode_closed_loop(2, sids=["a", "c"])
+    full = engines[0].collect_decoded()
+    eng = engines[1]
+    parts = {"a": eng.collect_decoded("a")["a"],
+             "b": eng.release("b").decoded["b"],
+             "c": eng.collect_decoded("c")["c"]}
+    for s in sids:
+        assert isinstance(parts[s], np.ndarray)
+        np.testing.assert_array_equal(parts[s], full[s])
+    assert full["a"].shape == (6, 1) and full["b"].shape == (4, 1)
+    empty = eng.collect_decoded("a")
+    assert empty["a"].shape == (0, 1) and empty.pulls == 0
+
+
+@pytest.mark.parametrize("form", ["all", "sid"])
+@pytest.mark.parametrize("rows", [1, 8])
+def test_a_decode_wave_is_copied_to_the_host_once(rows, form, monkeypatch):
+    """One closed-loop wave for 1 or 8 sessions makes exactly one host
+    copy, whether it is drained at once or one session at a time; the
+    dispatch itself waits for nothing and the mapping it returns reads the
+    same copy."""
+    params, readout, sig = _trained()
+    sids = [f"s{i}" for i in range(rows)]
+    eng = _engine(params, readout, sig, sids)
+    assert eng.max_slots >= rows
+    outs = _capture(eng, "_closed_jit")
+    out = eng.decode_closed_loop(4)
+    _no_device_indexing(monkeypatch)
+    if form == "all":
+        drains = [eng.collect_decoded()]
+    else:
+        drains = [eng.collect_decoded(s) for s in sids]
+    assert sum(d.pulls for d in drains) == 1
+    assert eng.collect_decoded().pulls == 0
+    ys = np.asarray(outs[0])
+    for s in sids:
+        (got,) = [d[s] for d in drains if s in d]
+        np.testing.assert_array_equal(got, ys[:, eng.sessions[s].slot])
+        np.testing.assert_array_equal(out[s], got)

@@ -30,7 +30,7 @@ PARENTS = {"serve.flush": {"serve.cycle"}, "serve.decode": {"serve.cycle"},
            "serve.route": {"serve.cycle"}, "serve.plan": {"serve.flush"},
            "serve.wave": {"serve.flush"},
            "serve.dispatch": {"serve.wave", "serve.decode"},
-           "serve.block": {"serve.wave", "serve.decode"},
+           "serve.block": {"serve.wave", "serve.decode", "serve.collect"},
            "serve.collect": {"serve.route"},
            "serve.release": {"serve.route"}}
 
@@ -42,6 +42,19 @@ def model_and_signal():
     return LinearESN.diagonalized(CFG).fit(u[:400], y[:400], washout=50), u
 
 
+async def _tokens(server, handle):
+    """The handle's tokens; raises what ended the serving loop, if the loop
+    ended first."""
+    got = asyncio.ensure_future(handle.tokens())
+    await asyncio.wait([got, server._task],
+                       return_when=asyncio.FIRST_COMPLETED)
+    if not got.done():
+        got.cancel()
+        server._task.result()
+        raise AssertionError("the serving loop ended before the stream")
+    return got.result()
+
+
 def _serve(model, u):
     """Three requests at once on two slots (one queues), then, after the
     loop has gone idle, a fourth; returns each request's tokens."""
@@ -51,10 +64,10 @@ def _serve(model, u):
         await server.start()
         handles = [await server.submit(i, u[16 * i:16 * i + 32], n_decode=5)
                    for i in range(3)]
-        toks = [await h.tokens() for h in handles]
+        toks = [await _tokens(server, h) for h in handles]
         await asyncio.sleep(0.02)
         late = await server.submit(3, u[100:140], n_decode=4)
-        toks.append(await late.tokens())
+        toks.append(await _tokens(server, late))
         await server.drain()
         return [np.stack([np.asarray(t.y) for t in ts]) for ts in toks]
     return asyncio.run(run())
@@ -129,6 +142,41 @@ def test_route_tokens_sum_to_the_tokens_delivered(traced):
     decoded = [s[3] for s in spans if s[0] == "serve.decode"]
     assert sum(d["rows"] * d["tokens"] for d in decoded) == sum(
         len(t) for t in tokens)
+
+
+def test_collect_pulls_each_decode_wave_once(traced):
+    """Every closed-loop wave's output crosses to the host in exactly one
+    copy, made by the ``serve.collect`` that drains it; ``waited`` counts
+    the copies whose wave was not yet computed, at most one per pull."""
+    _, spans = traced
+    collects = [s[3] for s in spans if s[0] == "serve.collect"]
+    waves = [s for s in spans if s[0] == "serve.dispatch"
+             and s[3]["program"] == "closed_loop_fused"]
+    assert sum(c["pulls"] for c in collects) == len(waves) > 0
+    assert all(0 <= c["waited"] <= c["pulls"] <= 1 for c in collects)
+
+
+def test_routing_touches_no_device_array(model_and_signal, monkeypatch):
+    """The front end routes host arrays: with a device array's
+    ``__iter__`` and ``__getitem__`` raising inside ``_route_tokens``, a
+    whole open-loop run still streams every token, bit-identical."""
+    plain = _serve(*model_and_signal)
+    array_type = type(jax.numpy.zeros(()))
+    route = OpenLoopServer._route_tokens
+
+    def boom(*args, **kw):
+        raise AssertionError("device array sliced while routing")
+
+    def guarded(server):
+        with monkeypatch.context() as m:
+            m.setattr(array_type, "__iter__", boom)
+            m.setattr(array_type, "__getitem__", boom)
+            return route(server)
+    monkeypatch.setattr(OpenLoopServer, "_route_tokens", guarded)
+    got = _serve(*model_and_signal)
+    assert [len(t) for t in got] == [5, 5, 5, 4]
+    for a, b in zip(plain, got):
+        np.testing.assert_array_equal(a, b)
 
 
 def test_tracing_leaves_the_tokens_bit_identical(traced, model_and_signal):

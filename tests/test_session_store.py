@@ -340,6 +340,44 @@ def test_snapshot_restore_resumes_mid_workload():
     assert res.store.stats()["epoch"] == eng.store.stats()["epoch"] + 1
 
 
+def test_snapshot_restore_carries_undrained_wave_tokens():
+    """Undrained tokens are buffered as handles on whole decode waves (a
+    closed-loop wave over two sessions, a single step, a wave over one);
+    a snapshot writes them as plain arrays and the restored engine drains
+    them as host arrays equal bit for bit to what the kernel emitted, as
+    the original engine does."""
+    params, readout, sig = _trained()
+    eng = ReservoirEngine(params, max_slots=3, readout=readout)
+    eng.submit("a", sig[50:66, None])
+    eng.submit("b", sig[80:96, None])
+    eng.flush()
+    outs = []
+    for name in ("_closed_jit", "_decode_jit"):
+        fn = getattr(eng._exec, name)
+
+        def call(*args, _fn=fn, **kw):
+            arena, ys = _fn(*args, **kw)
+            outs.append(ys)
+            return arena, ys
+        setattr(eng._exec, name, call)
+    eng.decode_closed_loop(3, sids=["a", "b"])
+    eng.decode_step({"a": sig[70, None]})
+    eng.decode_closed_loop(2, sids=["b"])
+    wave1, step, wave2 = (np.asarray(o) for o in outs)
+    sa, sb = eng.sessions["a"].slot, eng.sessions["b"].slot
+    want = {"a": np.concatenate([wave1[:, sa], step[sa][None]]),
+            "b": np.concatenate([wave1[:, sb], wave2[:, sb]])}
+
+    path = tempfile.mkdtemp(prefix="snap_") + "/engine"
+    eng.snapshot(path)
+    res = ReservoirEngine.restore(path)
+    for drained in (res.collect_decoded(), eng.collect_decoded()):
+        assert set(drained.tokens) == {"a", "b"}
+        for sid, rows in want.items():
+            assert isinstance(drained[sid], np.ndarray)
+            np.testing.assert_array_equal(drained[sid], rows)
+
+
 def test_snapshot_restore_carries_cost_model_key_and_fits():
     params, readout, sig = _trained()
     eng = ReservoirEngine(params, max_slots=2, readout=readout,
