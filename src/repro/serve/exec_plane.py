@@ -55,14 +55,17 @@ class DecodeResult:
     "interleave" / "driven", ``rows``, ``tokens`` per row, ``us`` wall time
     when timed, ``fused`` whether the K-token fused kernel ran) for the
     dispatches whose tokens this result drained.  ``pulls``: the host copies
-    this drain made; ``waited``: how many of them found the wave's output
-    not yet computed when the drain began; ``unready``: the sessions with a
-    token not yet computed then.  Mapping-shaped on ``tokens`` (iter / ``[]``
-    / ``items`` / ``get``), so dict-era callers keep working unchanged.
+    this drain made; ``pulled_bytes``: the bytes those copies moved (each
+    a whole wave's ``(K, max_slots, D_out)`` output); ``waited``: how many
+    of them found the wave's output not yet computed when the drain began;
+    ``unready``: the sessions with a token not yet computed then.
+    Mapping-shaped on ``tokens`` (iter / ``[]`` / ``items`` / ``get``), so
+    dict-era callers keep working unchanged.
     """
     tokens: Dict[Hashable, np.ndarray]
     waves: Tuple[dict, ...] = ()
     pulls: int = 0
+    pulled_bytes: int = 0
     waited: int = 0
     unready: int = 0
 
@@ -107,6 +110,10 @@ class _Wave:
     @property
     def pulled(self) -> bool:
         return self._host is not None
+
+    @property
+    def nbytes(self) -> int:
+        return (self._ys if self._host is None else self._host).nbytes
 
     def ready(self) -> bool:
         """Whether the output is computed (or already on the host)."""
@@ -941,6 +948,7 @@ class ExecPlane:
                   for s, c in bufs.items()}
         return DecodeResult(tokens=tokens, waves=tuple(waves),
                             pulls=len(ready),
+                            pulled_bytes=sum(w.nbytes for w in ready),
                             waited=sum(not r for r in ready.values()),
                             unready=unready)
 

@@ -201,7 +201,8 @@ class OpenLoopServer:
         with span("serve.route") as route:
             with span("serve.collect") as collect:
                 drained = self.engine.collect_decoded()
-                annotate(collect, pulls=drained.pulls, waited=drained.waited)
+                annotate(collect, pulls=drained.pulls, waited=drained.waited,
+                         bytes=drained.pulled_bytes)
             # The tokens are on the host now; ``unready`` counts the
             # sessions whose tokens the device had not computed when the
             # drain began (the drain waited for them).

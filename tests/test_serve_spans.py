@@ -156,6 +156,39 @@ def test_collect_pulls_each_decode_wave_once(traced):
     assert all(0 <= c["waited"] <= c["pulls"] <= 1 for c in collects)
 
 
+@pytest.mark.parametrize("d", [1, 8])
+def test_collect_bytes_are_the_whole_wave_output(tmp_path, d):
+    """``serve.collect``'s ``bytes`` counts what its pulls copied: one
+    request of K tokens is one wave, and its copy is the whole
+    ``(K, max_slots, D)`` output, empty slots included."""
+    k, slots = 3, 2
+    cfg = ESNConfig(n=32, d_in=d, d_out=d, spectral_radius=0.9, leak=0.85,
+                    ridge_alpha=1e-6, seed=9)
+    sig = mso_series(3, 1001)
+    sig = np.stack([np.roll(sig, 7 * j) for j in range(d)], axis=1)
+    u, y = sig[:-1], sig[1:]
+    model = LinearESN.diagonalized(cfg).fit(u[:400], y[:400], washout=50)
+
+    async def run():
+        server = OpenLoopServer(ReservoirEngine(
+            model, max_slots=slots, decode_wave_tokens=k))
+        await server.start()
+        toks = await _tokens(server, await server.submit(
+            0, u[:32], n_decode=k))
+        await server.drain()
+        return toks
+
+    with jax.profiler.trace(str(tmp_path)):
+        toks = asyncio.run(run())
+    assert len(toks) == k and np.asarray(toks[0].y).shape == (d,)
+    collects = [s[3] for s in _spans(tmp_path) if s[0] == "serve.collect"]
+    (pulled,) = [c for c in collects if c["pulls"]]
+    itemsize = np.asarray(toks[0].y).dtype.itemsize
+    assert pulled["pulls"] == 1
+    assert pulled["bytes"] == k * slots * d * itemsize
+    assert all(c["bytes"] == 0 for c in collects if not c["pulls"])
+
+
 def test_routing_touches_no_device_array(model_and_signal, monkeypatch):
     """The front end routes host arrays: with a device array's
     ``__iter__`` and ``__getitem__`` raising inside ``_route_tokens``, a

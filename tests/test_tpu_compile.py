@@ -1,7 +1,8 @@
 """Ahead-of-time compiles of the serving path for a TPU v5e that is
-described, not attached: the Pallas kernels at deployment width and the
-engine's jitted prefill-wave and closed-loop executables.  Each must
-compile and hold the kernel (``tpu_custom_call``).  Nothing runs.
+described, not attached: the Pallas kernels at each benchmark
+configuration's widths and the engine's jitted prefill-wave and closed-loop
+executables.  Each must compile and hold the kernel (``tpu_custom_call``).
+Nothing runs.
 
 The topology is described inside a module fixture, so only the worker that
 runs these tests loads the TPU compiler.  The engine picks its kernels from
@@ -20,8 +21,18 @@ from repro.core.params import Readout
 from repro.kernels import ops
 from repro.kernels.diag_scan import diag_scan_pallas_raw
 
-SLOTS, N, T, K = 64, 1024, 1024, 8
-NC = N // 2                    # complex lanes of an all-pairs spectrum
+SLOTS, K = 64, 8
+#: (N, D, prefill rows, prefill steps) of each benchmark configuration:
+#: mso-n1024 at its widest wave, ks22-n5000 (D=64) at its cell's widest
+#: prefill wave, 8 rows of 2048 steps.
+WIDTHS = {"mso-n1024": (1024, 1, SLOTS, 1024),
+          "ks22-n5000": (5000, 64, 8, 2048)}
+
+
+def _lanes(n):
+    """Complex lanes of an all-pairs spectrum, padded to whole 128-lane
+    tiles as the kernels' callers pad them."""
+    return -(-(n // 2) // 128) * 128
 
 
 @pytest.fixture(scope="module")
@@ -48,60 +59,71 @@ def _assert_kernel(lowered):
     assert "tpu_custom_call" in text
 
 
-def test_scan_kernel_compiles(one_chip):
-    x = _spec(one_chip, (SLOTS, T, NC))
-    h = _spec(one_chip, (SLOTS, NC))
+@pytest.mark.parametrize("widths", WIDTHS)
+def test_scan_kernel_compiles(one_chip, widths):
+    n, _, _, t = WIDTHS[widths]
+    x = _spec(one_chip, (SLOTS, t, _lanes(n)))
+    h = _spec(one_chip, (SLOTS, _lanes(n)))
     _assert_kernel(jax.jit(
         lambda *a: diag_scan_pallas_raw(*a, interpret=False)).lower(
             x, x, x, x, h, h))
 
 
-@pytest.mark.parametrize("weights", ["shared", "per_slot"])
-def test_decode_kernel_compiles(one_chip, weights):
+# Per-slot weights (a param batch or readout pool) compile at D=1 only: at
+# D=64 a slot tile's (8, 128, 2560) weight blocks need 60 MB of VMEM, past
+# the 16 MB scoped limit, and no configuration serves them there.
+@pytest.mark.parametrize("weights,widths", [
+    ("shared", "mso-n1024"), ("per_slot", "mso-n1024"),
+    ("shared", "ks22-n5000")])
+def test_decode_kernel_compiles(one_chip, weights, widths):
+    n, d, _, _ = WIDTHS[widths]
+    nc = n // 2
     s = lambda *shape: _spec(one_chip, shape)  # noqa: E731
     lead = (SLOTS,) if weights == "per_slot" else ()
-    args = (s(*lead, NC), s(*lead, NC), s(SLOTS, NC), s(SLOTS, NC),
-            s(SLOTS, 1), s(*lead, 1, NC), s(*lead, 1, NC), s(*lead, 1, 1),
-            s(*lead, 1), s(*lead, NC, 1), s(*lead, NC, 1),
+    args = (s(*lead, nc), s(*lead, nc), s(SLOTS, nc), s(SLOTS, nc),
+            s(SLOTS, d), s(*lead, d, nc), s(*lead, d, nc), s(*lead, d, d),
+            s(*lead, d), s(*lead, nc, d), s(*lead, nc, d),
             _spec(one_chip, (SLOTS,), jnp.bool_))
     _assert_kernel(jax.jit(
         lambda *a: ops.decode_fused(*a, k=K, interpret=False)).lower(*args))
 
 
-@pytest.fixture(scope="module")
-def engine_args():
-    """A float32 deployment-width engine and its arena; readout values do
-    not matter to a compile."""
-    params = esn_fn.dpg_params(ESNConfig(n=N, spectral_radius=0.95, leak=0.9,
+@pytest.fixture(scope="module", params=list(WIDTHS))
+def engine_args(request):
+    """A float32 engine at a configuration's widths, its arena and its
+    widest prefill wave; readout values do not matter to a compile."""
+    n, d, rows, t = WIDTHS[request.param]
+    params = esn_fn.dpg_params(ESNConfig(n=n, d_in=d, d_out=d,
+                                         spectral_radius=0.95, leak=0.9,
                                          input_scaling=0.5, seed=0),
                                "noisy_golden", sigma=0.01)
     params = jax.tree.map(lambda a: a.astype(jnp.float32), params)
-    readout = Readout(jnp.zeros((params.cfg.n_features, 1), jnp.float32))
-    return params, readout
+    readout = Readout(jnp.zeros((params.cfg.n_features, d), jnp.float32))
+    return params, readout, (rows, t, d)
 
 
 @pytest.fixture
 def tpu_engine(engine_args, one_chip, monkeypatch):
     from repro.serve import ReservoirEngine
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    params, readout = engine_args
+    params, readout, wave = engine_args
     eng = ReservoirEngine(params, max_slots=SLOTS, readout=readout)
     abstract = jax.tree.map(
         lambda a: _spec(one_chip, a.shape, a.dtype),
         (eng.params, eng._exec._wave_w(), eng.arena))
-    return eng._exec, abstract
+    return eng._exec, abstract, wave
 
 
 def test_prefill_wave_compiles(tpu_engine, one_chip):
-    ex, (params, w, arena) = tpu_engine
+    ex, (params, w, arena), (rows, t, d) = tpu_engine
     _assert_kernel(ex._wave_jit.lower(
-        params, w, arena, _spec(one_chip, (SLOTS,), jnp.int32),
-        _spec(one_chip, (SLOTS, T, 1)), _spec(one_chip, (SLOTS,), jnp.int32),
+        params, w, arena, _spec(one_chip, (rows,), jnp.int32),
+        _spec(one_chip, (rows, t, d)), _spec(one_chip, (rows,), jnp.int32),
         None, method="pallas", chunk=128, want_outputs=False))
 
 
 def test_closed_loop_compiles(tpu_engine, one_chip):
-    ex, (params, w, arena) = tpu_engine
+    ex, (params, w, arena), _ = tpu_engine
     _assert_kernel(ex._closed_jit.lower(
         params, w, arena, _spec(one_chip, (SLOTS,), jnp.bool_), K, None))
 
@@ -112,7 +134,7 @@ def test_sharded_executables_compile(tpu_engine, topo, executable):
     device under shard_map, so the sharded executables compile."""
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
     from repro.sharding.rules import plan_arena
-    ex, (params, w, arena) = tpu_engine
+    ex, (params, w, arena), (_, t, d) = tpu_engine
     mesh = Mesh(np.array(topo.devices).reshape(4, 1), ("data", "model"))
     plan = plan_arena(mesh, ex.params, SLOTS, readout=ex.readout)
     put = lambda a, sh: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh)  # noqa: E731
@@ -126,7 +148,7 @@ def test_sharded_executables_compile(tpu_engine, topo, executable):
         if executable == "prefill_wave":
             lowered = ex._wave_jit.lower(
                 params, w, arena, rows,
-                jax.ShapeDtypeStruct((SLOTS, T, 1), jnp.float32, sharding=rep),
+                jax.ShapeDtypeStruct((SLOTS, t, d), jnp.float32, sharding=rep),
                 rows, None, method="pallas", chunk=128, want_outputs=False)
         else:
             lowered = ex._closed_jit.lower(
