@@ -59,7 +59,6 @@ __all__ = [
     "place",
     "place_many",
     "gather_rows",
-    "release",
     "release_many",
     "force_output",
     "arena_step",
@@ -78,12 +77,15 @@ class SlotArena:
 
     ``states``: (B, N) recurrent state in the model's native basis (Q basis
     for diag models); ``y_prev``: (B, D_out) last output per slot (the
-    feedback column); ``active``: (B,) bool occupancy mask — the device-side
-    mirror of the engine's host-side slot table.  The compute functions take
-    explicit per-call ``mask`` arguments (which sessions to step is policy,
-    decided host-side); ``active`` records *occupancy* so device-resident
-    consumers (debug dumps, checkpointing a whole arena, future in-graph
-    admission) can read it without a host round-trip.
+    feedback column); ``active``: (B,) bool occupancy mask — a device-side
+    mirror of the engine's host-side slot table, which is authoritative.
+    The compute functions take explicit per-call ``mask`` arguments (which
+    sessions to step is policy, decided host-side), so no serving step reads
+    ``active``.  Releasing a session writes nothing here: the mirror is
+    written whole from the table by the next wave placement
+    (:func:`place_many`) and by every read through the engine
+    (``ReservoirEngine.arena``), so it is exact after every wave placement
+    and wherever it is read (checkpointing a whole arena, debug dumps).
     """
     states: jnp.ndarray
     y_prev: jnp.ndarray
@@ -112,13 +114,16 @@ def place(arena: SlotArena, slot: int, h0, y0) -> SlotArena:
                      active=arena.active.at[slot].set(True))
 
 
-def place_many(arena: SlotArena, slots, h0s, y0s) -> SlotArena:
+def place_many(arena: SlotArena, slots, h0s, y0s, occupied) -> SlotArena:
     """Write a whole wave of sessions in ONE scatter per array — per-slot
     ``place`` calls would cost 3 device dispatches each, which at wave sizes
-    dwarfs the batched prefill itself on CPU."""
+    dwarfs the batched prefill itself on CPU.  ``occupied``: the (B,) bool
+    occupancy of the host slot table, the wave's slots included; it
+    overwrites the whole ``active`` mirror, so slots freed since the last
+    write are marked free by this same dispatch."""
     return SlotArena(states=arena.states.at[slots].set(h0s),
                      y_prev=arena.y_prev.at[slots].set(y0s),
-                     active=arena.active.at[slots].set(True))
+                     active=arena.active.at[:].set(occupied))
 
 
 def gather_rows(arena: SlotArena, slots):
@@ -131,18 +136,12 @@ def gather_rows(arena: SlotArena, slots):
     return arena.states[idx], arena.y_prev[idx]
 
 
-def release(arena: SlotArena, slot: int) -> SlotArena:
-    """Mark ``slot`` free.  The state arrays are left in place — eviction
-    returns lazy slices of them, so zeroing here would race the caller."""
-    return SlotArena(states=arena.states, y_prev=arena.y_prev,
-                     active=arena.active.at[slot].set(False))
-
-
 def release_many(arena: SlotArena, slots) -> SlotArena:
     """Free a whole wave of slots in ONE scatter — the demote half of a page
     wave (``serve.store``): the engine gathers the victims' rows with one
-    ``device_get`` and then frees all their slots here.  Same
-    leave-the-arrays-in-place contract as :func:`release`."""
+    ``device_get`` and then frees all their slots here.  The state arrays
+    are left in place: the victims' rows were gathered first, and a freed
+    slot's row is overwritten by its next placement."""
     return SlotArena(states=arena.states, y_prev=arena.y_prev,
                      active=arena.active.at[slots].set(False))
 

@@ -409,7 +409,9 @@ class ReservoirEngine:
 
     @property
     def arena(self):
-        return self._exec.arena
+        """The slot arena, its ``active`` mirror equal to the slot table
+        (``ExecPlane.synced_arena``)."""
+        return self._exec.synced_arena()
 
     @arena.setter
     def arena(self, value):

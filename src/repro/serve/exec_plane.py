@@ -3,7 +3,7 @@ in-flight window with slot-granular taint tracking, tiered paging waves,
 and SLO-interleaved decode (free-running and teacher-driven).
 
 Everything that touches the device lives here: the jitted prefill /
-decode / place / release / gather dispatches, the per-slot readout pool's
+decode / place / page-out / gather dispatches, the per-slot readout pool's
 device side, the decode output buffers, and the flush drain loop that
 turns the scheduler's planned waves into dispatches.
 
@@ -234,6 +234,10 @@ class ExecPlane:
         self._arena_base = None
         self._base_valid = False
         self._base_dirty: set = set()
+        # Releases since the arena's ``active`` mirror was last written from
+        # the slot table: ``release`` makes no device call, and the next
+        # placement (or a read through ``synced_arena``) folds them in.
+        self._freed = 0
         # Every device function traces with full-precision dots (the engine
         # serves in its params' dtype, float32 on the TPU, not below it) and
         # with the arena's mesh in context (see core.dispatch.device_fn).
@@ -304,6 +308,19 @@ class ExecPlane:
                 y_prev=jax.device_put(ar.y_prev, self._plan.arena["y_prev"]),
                 active=jax.device_put(ar.active, self._plan.arena["active"]))
         return ar
+
+    def synced_arena(self) -> arena_mod.SlotArena:
+        """The arena with its ``active`` mirror equal to the slot table —
+        what a reader outside the plane gets.  After releases that no
+        placement has folded in yet, the table's occupancy is written first,
+        in one transfer; the serving path never reads the mirror, so this
+        never runs there."""
+        if self._freed:
+            self.arena = dataclasses.replace(
+                self.arena, active=jax.device_put(
+                    self.table.occupancy(), self.arena.active.sharding))
+            self._freed = 0
+        return self.arena
 
     @property
     def w_out(self):
@@ -495,7 +512,9 @@ class ExecPlane:
             self.table.sessions[sid] = st
             slots.append(slot)
         self.arena = self._place_jit(self.arena, jnp.asarray(slots),
-                                     jnp.asarray(states), jnp.asarray(ys))
+                                     jnp.asarray(states), jnp.asarray(ys),
+                                     self.table.occupancy())
+        self._freed = 0
         # Promoted sessions re-enter on fresh slots: re-scatter their tenant
         # pool readouts so the next decode wave serves the right weights.
         self.sync_slot_readouts(list(zip(sids, slots)))
@@ -1002,10 +1021,14 @@ class ExecPlane:
                 slots.append(slot)
                 self.note_admission(it.sid, it.req.tenant)
             touched.update(slots)
-            with span("serve.dispatch", program="place_many"):
+            # The placement writes the whole occupancy mirror from the
+            # table: the slots released since the last write ride it.
+            with span("serve.dispatch", program="place_many",
+                      freed=self._freed):
                 self.arena = self._place_jit(
                     self.arena, jnp.asarray(slots), jnp.asarray(h0s),
-                    jnp.asarray(y0s))
+                    jnp.asarray(y0s), self.table.occupancy())
+            self._freed = 0
             # Freshly placed slots must serve their tenant's pooled readout
             # from the first wave, not the engine-wide base.
             self.sync_slot_readouts(
@@ -1188,7 +1211,9 @@ class ExecPlane:
             state = self.arena.states[st.slot]
             y = self.arena.y_prev[st.slot]
         self.table.slots[st.slot] = None
-        self.arena = arena_mod.release(self.arena, st.slot)
+        # No device call: the occupancy mirror catches up at the next
+        # placement or read (see ``synced_arena``).
+        self._freed += 1
         # The freed slot may be re-placed outside wave bookkeeping — its
         # base row can no longer vouch for it, but every other row is
         # untouched: taint the one slot instead of dropping the base.
@@ -1204,6 +1229,7 @@ class ExecPlane:
         self._drain_inflight()
         self._pipeline_invalidate()
         self.arena = self._fresh_arena()
+        self._freed = 0
         self.table.clear()
         if self.store is not None:
             self.store.clear()

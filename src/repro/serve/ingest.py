@@ -80,6 +80,11 @@ class SessionTable:
     def free_slots(self) -> int:
         return self.slots.count(None)
 
+    def occupancy(self) -> np.ndarray:
+        """(max_slots,) bool: which slots hold a session — the value every
+        write of the arena's ``active`` mirror takes."""
+        return np.array([s is not None for s in self.slots], bool)
+
     def demotable(self, protect=frozenset()) -> List[Hashable]:
         """Hot sessions eligible to park, least-recently-used first: ready
         (no chunk waves in flight — a mid-prompt slot's carry is owed to

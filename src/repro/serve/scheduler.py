@@ -277,12 +277,16 @@ class WaveScheduler:
         items: List[WaveItem] = []
         fresh = 0
         for r in self._queue:
-            if r.sid in skip or self.bucket_of(r) != bucket:
+            # A fresh request past the capacity is skipped before its bucket
+            # is priced: with the slots full, a deep queue of fresh requests
+            # would otherwise cost every wave a pass of bucket and item work
+            # over all of them.
+            first = r.done == 0
+            if ((first and fresh >= capacity) or r.sid in skip
+                    or self.bucket_of(r) != bucket):
                 continue
             it = self._item(r)
-            if it.first:
-                if fresh >= capacity:
-                    continue
+            if first:
                 fresh += 1
             items.append(it)
             if self.max_wave is not None and len(items) >= self.max_wave:

@@ -189,6 +189,29 @@ def test_collect_bytes_are_the_whole_wave_output(tmp_path, d):
     assert all(c["bytes"] == 0 for c in collects if not c["pulls"])
 
 
+def test_placement_reports_the_releases_it_folds_in(model_and_signal,
+                                                    tmp_path):
+    """A release makes no device call; the next placement writes the
+    arena's occupancy mirror, and its ``serve.dispatch`` reports how many
+    releases that write folds in (``freed``)."""
+    model, u = model_and_signal
+    eng = ReservoirEngine(model, max_slots=2)
+    with jax.profiler.trace(str(tmp_path)):
+        eng.submit(0, u[:32])
+        eng.submit(1, u[32:64])
+        eng.flush()
+        eng.release(0, drop=True)
+        eng.release(1, drop=True)
+        eng.submit(2, u[64:96])
+        eng.flush()
+    places = sorted((s for s in _spans(tmp_path) if s[0] == "serve.dispatch"
+                     and s[3]["program"] == "place_many"),
+                    key=lambda s: s[1])
+    assert [s[3]["freed"] for s in places] == [0, 2]
+    np.testing.assert_array_equal(np.asarray(eng._exec.arena.active),
+                                  [True, False])
+
+
 def test_routing_touches_no_device_array(model_and_signal, monkeypatch):
     """The front end routes host arrays: with a device array's
     ``__iter__`` and ``__getitem__`` raising inside ``_route_tokens``, a

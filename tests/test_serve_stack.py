@@ -212,6 +212,30 @@ def test_scheduler_wave_is_single_bucket_and_ordered():
     assert sch.next_wave(8) == []
 
 
+def test_full_slots_leave_queued_fresh_requests_unpriced(monkeypatch):
+    """With every slot held, a chunk wave prices only the requests that can
+    ride it: the fresh requests queued behind are skipped before their
+    bucket is computed, so planning a wave does not grow with the queue."""
+    sch = WaveScheduler(bucket_min=16, chunk_max=64)
+    for i in range(3):
+        sch.submit(PrefillRequest(sid=("held", i), u=np.zeros((200, 1))))
+    assert [it.sid for it in sch.next_wave(3)] == [("held", i)
+                                                   for i in range(3)]
+    for i in range(200):
+        sch.submit(PrefillRequest(sid=("fresh", i), u=np.zeros((200, 1))))
+    priced = []
+    bucket_of = WaveScheduler.bucket_of
+
+    def counted(self, req):
+        priced.append(req.sid)
+        return bucket_of(self, req)
+    monkeypatch.setattr(WaveScheduler, "bucket_of", counted)
+    wave = sch.next_wave(0)
+    assert [(it.sid, it.start) for it in wave] == [(("held", i), 64)
+                                                   for i in range(3)]
+    assert priced and all(sid[0] == "held" for sid in priced)
+
+
 def test_evict_while_queued_cancels_prompt_request():
     params, readout, u, _ = _fitted()
     eng = ReservoirEngine(params, max_slots=1, readout=readout)
