@@ -477,7 +477,7 @@ def main(quick: bool = False):
         return out
 
     park_churn()                       # compile pass (traces + page scatter)
-    park_eng._promote_us.clear()       # p95 must price serving, not compiles
+    park_eng._agg.promote_us.clear()   # p95 must price serving, not compiles
     park_us = _util.timeit(park_churn, reps=3, warmup=0)
     park_tok = park_sessions * park_gen
     pst = park_eng.stats()
@@ -506,9 +506,7 @@ def main(quick: bool = False):
     # (pipeline_depth=0, io_workers=0) serializes it.  Reported: tok/s
     # both ways and overlap efficiency = 1 - host_idle/wall, where
     # host_idle is the engine's measured block_until_ready time.
-    # Arena geometry: one wave admits a quarter of the slots, so the window
-    # (depth 2) plus the admitting wave still leaves a retired slot-group
-    # for the overlap-demote fast path to gather from (>= depth+2 groups).
+    # Arena geometry: one wave admits a quarter of the slots.
     ov_slots = 4 * slots
     ov_grp = slots
     ov_rounds = 12 if quick else 16
@@ -563,8 +561,7 @@ def main(quick: bool = False):
         "overlap_efficiency": ov_eff,
         "rounds": ov_rounds, "group": ov_grp, "slots": ov_slots,
         "host_cores": os.cpu_count(),
-        "inflight_peak": ov_pipe.stats().pipeline_inflight_peak,
-        "overlap_demotes": ov_pipe.stats().overlap_demotes}
+        "inflight_peak": ov_pipe.stats().pipeline_inflight_peak}
     rows.append(_util.csv_row(
         "serve.pipeline.overlap", pipe_us,
         f"tok_s={ov_tok / (pipe_us * 1e-6):.0f};"

@@ -15,10 +15,11 @@ plus the c_dec(B, K) fused-decode surface.
 ``engine``    — ``ReservoirEngine``: the thin facade over the four serving
 planes (``telemetry`` observability, ``ingest`` control, ``exec_plane``
 data, ``learn`` learn-while-serving — one-way imports, enforced by test).
-The facade holds the public submit/flush/decode/release lifecycle, wires
-the cross-plane callbacks, and merges the planes' snapshots into the typed
-``EngineStats``.  Decode tokens drain through ``collect_decoded()`` as one
-typed ``DecodeResult`` of host numpy arrays whatever path produced them
+The facade holds the public submit/flush/decode/release lifecycle as its
+own members (it forwards no attribute to a plane), wires the cross-plane
+callbacks, and merges the planes' counters into the typed ``EngineStats``.
+Decode tokens drain through ``collect_decoded()`` as one typed
+``DecodeResult`` of host numpy arrays whatever path produced them
 (each decode wave's output crosses to the host in one copy, started at
 dispatch); with ``learn=True`` the learn plane accumulates streaming
 eigenbasis ``(G, C)`` off the ``observe()`` teacher path, refits batched
@@ -37,10 +38,12 @@ graceful drain); ``benchmarks/loadgen.py`` drives it at fixed offered load.
 cold tier; a full arena parks its LRU
 idle sessions in batched page waves (priced by the cost model's
 ``kind:"page"`` surface) instead of rejecting admissions, and decode on a
-parked session promotes it transparently.  ``snapshot_engine`` /
-``restore_engine`` (surfaced as ``engine.snapshot()`` /
-``ReservoirEngine.restore()``) serialize the whole serving process for
-drain/upgrade/resume.
+parked session promotes it transparently.  It imports no other serve
+module.
+``snapshot``  — ``snapshot_engine`` / ``restore_engine`` (surfaced as
+``engine.snapshot()`` / ``ReservoirEngine.restore()``) serialize the whole
+serving process for drain/upgrade/resume, reading each plane's state
+through that plane's export/load pair; only the engine imports it.
 
 Backend selection lives in ``core.dispatch`` (the PR-2-era ``serve.dispatch``
 re-export shim is gone); ``resolve_method`` / ``run_scan_q`` stay re-exported

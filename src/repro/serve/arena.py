@@ -30,18 +30,11 @@ closed loop (state feedback per Ehlers et al. 2023 stays bit-exact: the
 feedback column simply carries the ensemble mean instead of the per-slot
 prediction).
 
-**Aliasing under the pipelined executor.**  Every function here is
-value-semantic: it returns a *new* ``SlotArena`` whose arrays share no
-mutable storage with the input's (XLA buffers are immutable unless
-donated).  The engine's pipelined executor leans on that: while a wave is
-in flight it may gather page-out rows from the *pre-wave* arena value —
-legal precisely because the older value is a live, unaliased buffer whose
-untouched rows are bit-identical to the post-wave value (scatters only
-write their own slots).  The ONE exception is donation: when the engine
-compiles its wave step with ``donate_argnums`` (TPU), the input arena's
-buffers may be reused in place by XLA, so a superseded arena value must
-never be read again — the engine gates the fast path off under donation
-(see ``ReservoirEngine._demote_wave``).
+**Aliasing.**  Every function here is value-semantic: it returns a *new*
+``SlotArena``.  One rule covers every caller: a superseded arena value is
+never read.  The exec plane replaces its arena with each function's result
+and reads only the current value; on the TPU the closed-loop decode
+donates its input arena, whose buffers XLA then reuses in place.
 """
 from __future__ import annotations
 
@@ -129,9 +122,7 @@ def place_many(arena: SlotArena, slots, h0s, y0s, occupied) -> SlotArena:
 def gather_rows(arena: SlotArena, slots):
     """Lazy device slices of ``slots``'s (states, y_prev) rows — the gather
     half of a demote page wave.  Returns device arrays (no host sync): the
-    caller picks when to pay the transfer (``jax.device_get``).  Safe to
-    call on a *superseded* arena value (the pipelined demote fast path) as
-    long as that value was not donated — see the module docstring."""
+    caller picks when to pay the transfer (``jax.device_get``)."""
     idx = jnp.asarray(slots)
     return arena.states[idx], arena.y_prev[idx]
 

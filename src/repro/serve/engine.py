@@ -24,12 +24,12 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core.params import DiagParams, Readout, StandardParams
+from . import snapshot as snapshot_mod
 from . import store as store_mod
 from .cost import WaveCostModel, cost_key
 from .exec_plane import DecodeResult, EvictResult, ExecPlane
 from .ingest import AdmissionFull, IngestPlane, SessionStats, SessionTable
-from .learn import (LearnPlane, _GramAcc, _LearnState,  # noqa: F401
-                    _Member)
+from .learn import LearnPlane
 from .scheduler import WaveScheduler
 from .telemetry import (EngineStats, MultiTracker, StatsAggregator, Tracker,
                         make_tracker)
@@ -53,63 +53,6 @@ def _coerce_model(model, readout):
     if readout is not None and not isinstance(readout, Readout):
         readout = Readout(jnp.asarray(readout))
     return params, readout
-
-
-# Exec-plane internals historically reachable as engine attributes (tests,
-# benchmarks, and snapshot restore poke them); forwarded read-only via
-# __getattr__ so the facade stays thin without breaking the compat surface.
-# Restore only ever *mutates* these containers (``eng._decode_buf[sid] =``),
-# never rebinds the attribute, so read-only forwarding is enough.
-_EXEC_FWD = frozenset({
-    "_arena_base", "_base_valid", "_base_dirty", "_donate", "_slot_w",
-    "_ens_weights", "_wave_w", "_demote_wave", "_promote_wave",
-    "_ensure_hot", "_make_room", "_capacity", "_demotable",
-    "_inflight_admit", "_inflight_retire", "_drain_inflight",
-    "_window_settled", "_pipeline_invalidate", "_pipeline_taint",
-    "_inflight_dirty_slots", "_decode_wave", "_driven_wave",
-    "_dispatch_decode", "_note_decode", "_run_wave", "_record_wave",
-    "_note_page", "_base_readout", "_pool_readout", "_fresh_arena",
-    "_decode_budget", "_decode_jit", "_closed_jit", "_driven_jit",
-    "_wave_jit", "_place_jit", "_release_jit", "_gather_jit", "_active",
-    "_inflight", "_decode_buf", "_decode_meta", "_chunk_outs",
-    "_decode_k_auto", "pipeline_depth",
-})
-
-#: other live views and method delegations: facade name -> (plane, name).
-#: The bound plane method carries the canonical docstring — the facade adds
-#: nothing to these, so it forwards instead of wrapping.
-_PLANE_FWD = {
-    "sessions": ("_table", "sessions"),
-    "_slots": ("_table", "slots"),
-    "active_sessions": ("_table", "active"),
-    "ready_sessions": ("_table", "ready"),
-    "free_slots": ("_table", "free_slots"),
-    "_tick": ("_table", "tick"),
-    "_learn_state": ("_learn_plane", "state"),
-    "_readouts": ("_learn_plane", "readouts"),
-    "_promote_us": ("_agg", "promote_us"),
-    "max_queued": ("_ingest", "max_queued"),
-    # control plane
-    "queue_inputs": ("_ingest", "queue_inputs"),
-    # data plane
-    "_place": ("_exec", "place"),
-    "state_of": ("_exec", "state_of"),
-    "decode_step": ("_exec", "decode_step"),
-    "observe": ("_exec", "observe"),
-    "decode_closed_loop": ("_exec", "decode_closed_loop"),
-    "collect_decoded": ("_exec", "collect_decoded"),
-    "_activate_pool": ("_exec", "activate_pool"),
-    "_sync_slot_readouts": ("_exec", "sync_slot_readouts"),
-    # learn plane
-    "drift_rmse": ("_learn_plane", "drift_rmse"),
-    "_refit_wave": ("_learn_plane", "refit_wave"),
-    "_fold_acc": ("_learn_plane", "_fold_acc"),
-    "_session_params": ("_learn_plane", "_session_params"),
-    "_note_admission": ("_learn_plane", "note_admission"),
-    "_readout_key": ("_learn_plane", "readout_key"),
-    # telemetry plane
-    "clear_decode_gaps": ("_agg", "clear_gaps"),
-}
 
 
 class ReservoirEngine:
@@ -326,6 +269,18 @@ class ReservoirEngine:
         """Cross-plane runtime effects travel through these callbacks so
         imports stay one-way; the closures read live facade state."""
         ex, ig, ln = self._exec, self._ingest, self._learn_plane
+        # The facade's own decode, input-queue and telemetry surface: the
+        # owning plane's bound method, whose docstring carries the contract.
+        self.decode_step = ex.decode_step
+        self.observe = ex.observe
+        self.decode_closed_loop = ex.decode_closed_loop
+        self.collect_decoded = ex.collect_decoded
+        self.state_of = ex.state_of
+        self.queue_inputs = ig.queue_inputs
+        self.input_depth = ig.input_depth
+        self.pop_inputs = ig.pop_inputs
+        self.drift_rmse = ln.drift_rmse
+        self.clear_decode_gaps = self._agg.clear_gaps
         # exec -> learn (teacher pairing, voting, refit) and -> ingest
         # (open-loop input queues).
         ex.note_admission = ln.note_admission
@@ -394,36 +349,34 @@ class ReservoirEngine:
                    park_host_rows=park_host_rows, cold_dir=cold_dir,
                    tracker=tracker, max_queued=max_queued, _param_batch=True)
 
-    # ------------------------------------------------- plane state (compat)
+    # ------------------------------------------------------- plane views
     # The facade owns NO serving state: every attribute below is a live
-    # view into the plane that does.  Assignments propagate where the old
-    # monolith allowed them (snapshot restore, tests).
-    def __getattr__(self, name):
-        if name in _EXEC_FWD:
-            return getattr(object.__getattribute__(self, "_exec"), name)
-        fwd = _PLANE_FWD.get(name)
-        if fwd is not None:
-            return getattr(object.__getattribute__(self, fwd[0]), fwd[1])
-        raise AttributeError(
-            f"{type(self).__name__!r} object has no attribute {name!r}")
+    # view into the plane that does.
+    @property
+    def sessions(self) -> Dict[Hashable, SessionStats]:
+        return self._table.sessions
+
+    @property
+    def active_sessions(self) -> List[Hashable]:
+        return self._table.active
+
+    @property
+    def ready_sessions(self) -> List[Hashable]:
+        return self._table.ready
+
+    @property
+    def free_slots(self) -> int:
+        return self._table.free_slots
+
+    @property
+    def max_queued(self) -> Optional[int]:
+        return self._ingest.max_queued
 
     @property
     def arena(self):
         """The slot arena, its ``active`` mirror equal to the slot table
         (``ExecPlane.synced_arena``)."""
         return self._exec.synced_arena()
-
-    @arena.setter
-    def arena(self, value):
-        self._exec.arena = value
-
-    @property
-    def _use_clock(self) -> int:
-        return self._table.use_clock
-
-    @_use_clock.setter
-    def _use_clock(self, value: int) -> None:
-        self._table.use_clock = int(value)
 
     @property
     def scheduler(self) -> WaveScheduler:
@@ -508,7 +461,7 @@ class ReservoirEngine:
         the tenant's hot sessions switch together)."""
         self._exec.sync_slot_readouts(
             [(sid, st.slot) for sid, st in self.sessions.items()
-             if self._readout_key(sid) == key])
+             if self._learn_plane.readout_key(sid) == key])
 
     def set_readout(self, key: Hashable, w_out) -> None:
         """Install/replace the pool readout for ``key`` (a tenant, or a sid
@@ -521,7 +474,7 @@ class ReservoirEngine:
             raise ValueError(f"pool readout for {key!r} must be {want}, "
                              f"got {tuple(w.shape)}")
         self._exec.activate_pool()
-        self._readouts[key] = w
+        self._learn_plane.readouts[key] = w
         self._sync_key(key)
 
     def readout_for(self, sid):
@@ -635,7 +588,7 @@ class ReservoirEngine:
             store=None if self.store is None else self.store.stats(),
             chunks_in_flight=sum(st.prefill_pending
                                  for st in self.sessions.values()),
-            pipeline_depth=self.pipeline_depth,
+            pipeline_depth=self._exec.pipeline_depth,
             pipeline_inflight=len(self._exec._inflight),
             sessions_dirty=sum(ls.dirty
                                for ls in self._learn_plane.state.values()),
@@ -678,17 +631,12 @@ class ReservoirEngine:
         """Serialize the whole serving process to ``path`` — everything
         :meth:`restore` needs to resume mid-workload bit-exactly.  Atomic
         (tmp-rename + ``_COMPLETE`` marker).  See
-        ``serve.store.snapshot_engine``."""
-        return store_mod.snapshot_engine(self, path)
+        ``serve.snapshot.snapshot_engine``."""
+        return snapshot_mod.snapshot_engine(self, path)
 
     @classmethod
     def restore(cls, path: str, *, mesh=None) -> "ReservoirEngine":
         """Rebuild an engine from :meth:`snapshot` output and resume
         serving bit-exactly (pinned by test).  ``mesh`` re-places the
         arena on a new device mesh.  Stats counters start fresh."""
-        return store_mod.restore_engine(cls, path, mesh=mesh)
-
-    # Decode (``decode_step`` / ``observe`` / ``decode_closed_loop`` /
-    # ``collect_decoded``), ``queue_inputs``, ``state_of``, ``drift_rmse``
-    # and ``clear_decode_gaps`` forward straight to their owning plane via
-    # ``_PLANE_FWD`` — the bound plane method carries the contract.
+        return snapshot_mod.restore_engine(cls, path, mesh=mesh)

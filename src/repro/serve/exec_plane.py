@@ -1,6 +1,6 @@
 """Exec (data) plane — wave dispatch against the slot arena, the pipelined
-in-flight window with slot-granular taint tracking, tiered paging waves,
-and SLO-interleaved decode (free-running and teacher-driven).
+in-flight window, tiered paging waves, and SLO-interleaved decode
+(free-running and teacher-driven).
 
 Everything that touches the device lives here: the jitted prefill /
 decode / place / page-out / gather dispatches, the per-slot readout pool's
@@ -208,7 +208,7 @@ class ExecPlane:
         self.pipeline_depth = int(pipeline_depth)
         self.decode_slo_us = decode_slo_us
         self.decode_wave_tokens = int(decode_wave_tokens)
-        self._decode_k_auto = bool(decode_k_auto)
+        self.decode_k_auto = bool(decode_k_auto)
         self.store = store
         self.cost_model = cost_model
         self._autotune = bool(autotune)
@@ -222,18 +222,9 @@ class ExecPlane:
         self._decode_buf: Dict[Hashable, List] = {}
         self._decode_meta: List[dict] = []
         # Pipelined-executor window: dispatched-but-unretired waves, oldest
-        # first.  Each entry carries the lazy output to block on (marker),
-        # the cost model's predicted wave cost (the window bound), the slot
-        # set the wave writes, and the arena value right after its dispatch.
-        # ``_arena_base`` is the arena as of the oldest in-flight wave's
-        # *inputs* — a donation-free backend may gather untouched rows from
-        # it without waiting for the in-flight scans (see _demote_wave);
-        # ``_base_valid`` drops to False whenever an untracked path mutates
-        # the arena while waves are in flight.
+        # first.  Each entry carries the lazy output to block on (marker)
+        # and the cost model's predicted wave cost (the window bound).
         self._inflight = collections.deque()
-        self._arena_base = None
-        self._base_valid = False
-        self._base_dirty: set = set()
         # Releases since the arena's ``active`` mirror was last written from
         # the slot table: ``release`` makes no device call, and the next
         # placement (or a read through ``synced_arena``) folds them in.
@@ -251,16 +242,10 @@ class ExecPlane:
         # (arena.closed_loop_fused -> core.dispatch.run_decode_fused): one
         # dispatch per wave instead of per token, Pallas kernel on TPU, jnp
         # reference elsewhere; dense params fall back to the scan inside.
-        # The arena argument is donated on TPU so the (B, N) slot state
-        # updates in place — never copies per wave (donation elsewhere is a
-        # no-op that XLA warns about, so it is gated).
+        # On the TPU the closed-loop decode donates its arena argument, so
+        # the (B, N) slot state updates in place instead of being copied
+        # each wave; the superseded arena value is never read again.
         donate = (2,) if jax.default_backend() == "tpu" else ()
-        # Donation-safety flag for the pipelined executor: with the arena
-        # donated (TPU), a superseded arena's buffer may already be reused
-        # in place, so gathering from a pre-wave arena value while the wave
-        # is in flight would read freed memory — the overlap-demote fast
-        # path is gated off and demotes fall back to the ordered gather.
-        self._donate = bool(donate)
         self._closed_jit = jax.jit(
             on_device(functools.partial(arena_mod.closed_loop_fused,
                                         batched=self._batched,
@@ -300,14 +285,35 @@ class ExecPlane:
         self.refit_wave = lambda sids: {}
 
     def _fresh_arena(self) -> arena_mod.SlotArena:
-        ar = arena_mod.make_arena(self.cfg.n, self.cfg.d_out, self.max_slots,
-                                  self._dtype)
-        if self._plan is not None:
-            ar = arena_mod.SlotArena(
-                states=jax.device_put(ar.states, self._plan.arena["states"]),
-                y_prev=jax.device_put(ar.y_prev, self._plan.arena["y_prev"]),
-                active=jax.device_put(ar.active, self._plan.arena["active"]))
-        return ar
+        return self._on_plan(arena_mod.make_arena(
+            self.cfg.n, self.cfg.d_out, self.max_slots, self._dtype))
+
+    def _on_plan(self, ar: arena_mod.SlotArena) -> arena_mod.SlotArena:
+        if self._plan is None:
+            return ar
+        return arena_mod.SlotArena(
+            states=jax.device_put(ar.states, self._plan.arena["states"]),
+            y_prev=jax.device_put(ar.y_prev, self._plan.arena["y_prev"]),
+            active=jax.device_put(ar.active, self._plan.arena["active"]))
+
+    def export_state(self) -> dict:
+        """What an engine snapshot holds of this plane: the arena (its
+        ``active`` mirror synced), the undrained decode buffers (sid -> row
+        chunks), their per-dispatch metadata, and the buffered chunk
+        outputs (sid -> output chunks)."""
+        return {"arena": self.synced_arena(), "decode_buf": self._decode_buf,
+                "decode_meta": self._decode_meta,
+                "chunk_outs": self._chunk_outs}
+
+    def load_state(self, arena: arena_mod.SlotArena, decode_buf,
+                   decode_meta, chunk_outs) -> None:
+        """Install :meth:`export_state`'s pieces, the arena placed on the
+        engine's mesh."""
+        self.arena = self._on_plan(arena)
+        self._freed = 0
+        self._decode_buf.update(decode_buf)
+        self._decode_meta.extend(decode_meta)
+        self._chunk_outs.update(chunk_outs)
 
     def synced_arena(self) -> arena_mod.SlotArena:
         """The arena with its ``active`` mirror equal to the slot table —
@@ -358,8 +364,7 @@ class ExecPlane:
         self.scheduler.charge_decode_cost(us)
 
     # ---------------------------------------------------- pipelined executor
-    def _inflight_admit(self, marker, pred_us: float, slots,
-                        arena_before) -> None:
+    def _inflight_admit(self, marker, pred_us: float) -> None:
         """Admit a freshly dispatched wave into the in-flight window, then
         retire from the front until the window is legal again: at most
         ``pipeline_depth`` waves deep, AND — when a decode SLO is set — the
@@ -367,18 +372,7 @@ class ExecPlane:
         unbounded dispatch queue is exactly how async dispatch blows a
         latency SLO: every queued wave is latency someone's next token must
         wait behind)."""
-        if not self._inflight:
-            # Window was empty: the pre-dispatch lineage is fully retired,
-            # so the arena value the wave read from is a safe gather source
-            # for rows no in-flight wave touches.  The base is captured
-            # fresh, past every earlier out-of-band mutation — the taint
-            # set starts clean.
-            self._arena_base = arena_before
-            self._base_valid = True
-            self._base_dirty = set()
-        self._inflight.append({"marker": marker, "pred_us": float(pred_us),
-                               "slots": frozenset(slots),
-                               "arena_after": self.arena})
+        self._inflight.append({"marker": marker, "pred_us": float(pred_us)})
         while len(self._inflight) > self.pipeline_depth or (
                 self.decode_slo_us is not None and len(self._inflight) > 1
                 and sum(e["pred_us"] for e in self._inflight)
@@ -388,10 +382,9 @@ class ExecPlane:
                                "inflight": len(self._inflight)})
 
     def _inflight_retire(self) -> None:
-        """Block on the oldest in-flight wave and advance the safe gather
-        base past it.  The blocked time is the host's pipeline-idle time —
-        accounted so the overlap-efficiency benchmark can report
-        1 - host_idle/wall."""
+        """Block on the oldest in-flight wave.  The blocked time is the
+        host's pipeline-idle time — accounted so the overlap-efficiency
+        benchmark can report 1 - host_idle/wall."""
         e = self._inflight.popleft()
         t0 = time.perf_counter()
         # Under donation (TPU) a later dispatch may have consumed the
@@ -402,10 +395,6 @@ class ExecPlane:
                 jax.block_until_ready(e["marker"])
         self.tracker.log_wave({"kind": "host_block",
                                "us": (time.perf_counter() - t0) * 1e6})
-        if self._base_valid:
-            self._arena_base = e["arena_after"]
-        if not self._inflight:
-            self._arena_base = None
 
     def _drain_inflight(self) -> None:
         while self._inflight:
@@ -416,71 +405,18 @@ class ExecPlane:
         wave (a decode wave's tokens, a promote's scatter): the whole window
         is materialized — forget it without further blocking."""
         self._inflight.clear()
-        self._pipeline_invalidate()
-
-    def _pipeline_invalidate(self) -> None:
-        """An arena mutation outside the tracked wave path whose touched
-        rows are unknown (an unmasked decode, a wholesale arena swap): the
-        pre-wave gather base can no longer vouch for any row — fall back to
-        ordered gathers until the window turns over."""
-        self._arena_base = None
-        self._base_valid = False
-        self._base_dirty = set()
-
-    def _pipeline_taint(self, slots) -> None:
-        """A *known-slot* arena mutation outside the tracked wave path
-        (evict release, single-session place, teacher-forcing): the gather
-        base stays valid for every OTHER row — only the touched slots fall
-        back to ordered gathers.  Slot-granular where
-        :meth:`_pipeline_invalidate` is wholesale, so steady churn (evicts
-        every round) doesn't permanently kill the overlap-demote fast path.
-        """
-        if self._base_valid:
-            self._base_dirty.update(slots)
-
-    def _inflight_dirty_slots(self) -> set:
-        dirty: set = set()
-        for e in self._inflight:
-            dirty |= e["slots"]
-        return dirty
 
     def _demote_wave(self, sids: List[Hashable]) -> None:
-        """Park ``sids``: gather their slot rows in ONE device->host
-        transfer, free the slots in ONE scatter, and hand the rows (plus
-        each session's accounting struct, verbatim) to the store.  The
-        ``device_get`` is inherently blocking — but on a donation-free
-        backend, a pipelined engine gathers from the **pre-wave arena
-        value** when no in-flight wave touches the victim slots: those rows
-        are bit-identical in both values (waves scatter only their own
-        slots), and the older value does not depend on the in-flight scans,
-        so the page-out overlaps them instead of draining the window.  With
-        the arena donated (TPU) the superseded buffer may already be reused
-        in place, so the fast path is gated off (donation safety)."""
+        """Park ``sids``: gather their slot rows from the current arena in
+        ONE device->host transfer, free the slots in ONE scatter, and hand
+        the rows (plus each session's accounting struct, verbatim) to the
+        store.  The ``device_get`` blocks, and the gather runs after every
+        in-flight wave that writes the arena."""
         if not sids:
             return
-        slots = [self.table.sessions[s].slot for s in sids]
-        idx = jnp.asarray(slots)
-        if (self._inflight and self._base_valid and not self._donate
-                and self._arena_base is not None
-                and not (set(slots) & (self._inflight_dirty_slots()
-                                       | self._base_dirty))):
-            # Overlap fast path: the base value was materialized by the
-            # last retire, so device_get here waits only on its own ready
-            # event and copies — no gather computation is enqueued.  An
-            # enqueued gather would serialize behind the in-flight scan on
-            # backends that execute in dispatch order (CPU), turning the
-            # "overlap" into a hidden drain.  The row select runs on host.
-            base = self._arena_base
-            self.tracker.log_wave({"kind": "overlap_demote",
-                                   "rows": len(sids)})
-            t0 = time.perf_counter()
-            all_states, all_ys = jax.device_get((base.states, base.y_prev))
-            sel = np.asarray(slots)
-            states, ys = all_states[sel], all_ys[sel]
-        else:
-            t0 = time.perf_counter()
-            states, ys = jax.device_get(
-                self._gather_jit(self.arena, idx))
+        idx = jnp.asarray([self.table.sessions[s].slot for s in sids])
+        t0 = time.perf_counter()
+        states, ys = jax.device_get(self._gather_jit(self.arena, idx))
         us = (time.perf_counter() - t0) * 1e6
         stats = []
         for sid in sids:
@@ -662,7 +598,7 @@ class ExecPlane:
                 for s in decode_sids:
                     if self.scheduler.decode_slo_of(s) is None:
                         self.scheduler.track_decode(s, self.decode_slo_us)
-            if self._decode_k_auto and self.cost_model is not None:
+            if self.decode_k_auto and self.cost_model is not None:
                 # K-adaptive wave sizing: resolve decode_wave_tokens for
                 # this flush from the fitted c_dec(B, K) surface — largest
                 # K whose marginal cost/token still improves, capped so the
@@ -732,13 +668,10 @@ class ExecPlane:
                     and self.store is not None):
                 # Plan one wave ahead against *predicted* post-wave
                 # occupancy (pure host bookkeeping — the slot table is
-                # already updated at dispatch time, no device ground truth
-                # needed) and run the planned wave's page-out NOW: the
-                # demote gather reads untouched rows from the pre-wave
-                # arena value, so it overlaps the in-flight scan instead of
-                # draining the pipeline.  The next iteration's next_wave
-                # pops exactly this wave (peek is exact), and _make_room
-                # then finds the slots already free.
+                # already updated at dispatch time) and run the planned
+                # wave's page-out now.  The next iteration's next_wave pops
+                # exactly this wave (peek is exact), and _make_room then
+                # finds the slots already free.
                 with span("serve.plan"):
                     planned = self.scheduler.peek_wave(
                         self._capacity(protect))
@@ -796,7 +729,7 @@ class ExecPlane:
 
     def _dispatch_decode(self, launch, sids, *, tokens: int,
                          block: bool, interleave: bool = False,
-                         kind: str = "closed_loop", slots=None):
+                         kind: str = "closed_loop"):
         """Shared wrapper around every decode dispatch: optional wall timing
         (always when ``block``, else only under autotune), decode-surface
         observation (autotune only — there every prefill wave was itself
@@ -804,13 +737,9 @@ class ExecPlane:
         block also drains queued prefill waves, and that drain time would
         poison the fit), and the gap/counter/deadline accounting.
         ``launch`` performs the jitted call, stores the new arena, and
-        returns the output array to block on.  ``slots`` (pipelined,
-        unblocked path): the slot set the dispatch writes — known exactly
-        (it is the decode mask), so the dispatch is admitted into the
-        in-flight window as a tracked writer instead of invalidating the
-        demote fast path's base arena."""
+        returns the output array to block on; unblocked, the dispatch joins
+        the in-flight window."""
         timed = (block or self._autotune) and sids and tokens
-        arena_before = self.arena
         t0 = time.perf_counter() if timed else None
         out = launch()
         us = None
@@ -827,16 +756,11 @@ class ExecPlane:
                 # c_dec(B, K) surface — dividing by K would erase the very
                 # dispatch amortization the fused kernel buys.
                 self.cost_model.observe_decode(len(sids), us, k=tokens)
-        elif self.pipeline_depth > 0 and slots is not None:
+        elif self.pipeline_depth > 0:
             pred = (self.cost_model.predict_decode_us(len(sids), tokens)
                     if self.cost_model is not None and sids and tokens
                     else 1.0)
-            self._inflight_admit(out, pred, set(slots), arena_before)
-        else:
-            # Unblocked decode dispatch mutating arena rows the in-flight
-            # bookkeeping didn't record — the demote fast path's base arena
-            # is no longer trustworthy.
-            self._pipeline_invalidate()
+            self._inflight_admit(out, pred)
         if sids and tokens:
             self._note_decode(sids, us=us, tokens=tokens,
                               interleave=interleave, kind=kind)
@@ -1001,8 +925,6 @@ class ExecPlane:
         # .at[] sets are device dispatches; at wave sizes they'd dwarf the
         # scan).  Continuation rows already own their slot.
         from .ingest import SessionStats
-        arena_before = self.arena
-        touched: set = set()
         fresh = [it for it in wave if it.first]
         if fresh:
             h0s = np.zeros((len(fresh), self.cfg.n), self._dtype)
@@ -1020,7 +942,6 @@ class ExecPlane:
                     y0s[i] = np.asarray(it.req.y0)
                 slots.append(slot)
                 self.note_admission(it.sid, it.req.tenant)
-            touched.update(slots)
             # The placement writes the whole occupancy mirror from the
             # table: the slots released since the last write ride it.
             with span("serve.dispatch", program="place_many",
@@ -1037,8 +958,7 @@ class ExecPlane:
         if not prompts:
             self._record_wave(0, len(wave), len(fresh), capacity, 0, None)
             if fresh and self.pipeline_depth > 0 and not self._autotune:
-                self._inflight_admit(self.arena.states, 1.0, touched,
-                                     arena_before)
+                self._inflight_admit(self.arena.states, 1.0)
             return                  # admission-only wave (bucket 0)
         t_bucket = self._bucket(prompts)
         bw = len(prompts)
@@ -1052,9 +972,8 @@ class ExecPlane:
             lengths[i] = t
             if yt_pad is not None:
                 yt_pad[i, :t] = it.req.y_teacher[it.start:it.stop]
-        slot_list = [self.table.sessions[it.sid].slot for it in prompts]
-        touched.update(slot_list)
-        slots = jnp.asarray(slot_list)
+        slots = jnp.asarray([self.table.sessions[it.sid].slot
+                             for it in prompts])
         wave_method = method
         if wave_method == "auto" and self.params.mode == "diag":
             wave_method = dispatch.resolve_method(t_bucket, chunk=chunk)
@@ -1092,8 +1011,7 @@ class ExecPlane:
         else:
             pred = (self.cost_model.predict_us(bw, t_bucket)
                     if self.cost_model is not None else 1.0)
-            self._inflight_admit(self.arena.states, pred, touched,
-                                 arena_before)
+            self._inflight_admit(self.arena.states, pred)
         tokens = int(lengths.sum())
         self._record_wave(t_bucket, len(wave), len(fresh), capacity,
                           tokens, us)
@@ -1163,7 +1081,6 @@ class ExecPlane:
         self.arena = arena_mod.place(self.arena, slot,
                                      h0.astype(self._dtype),
                                      y0.astype(self._dtype))
-        self._pipeline_taint([slot])
         self.table.slots[slot] = sid
         self.table.sessions[sid] = SessionStats(slot=slot)
         self.sync_slot_readouts([(sid, slot)])
@@ -1214,10 +1131,6 @@ class ExecPlane:
         # No device call: the occupancy mirror catches up at the next
         # placement or read (see ``synced_arena``).
         self._freed += 1
-        # The freed slot may be re-placed outside wave bookkeeping — its
-        # base row can no longer vouch for it, but every other row is
-        # untouched: taint the one slot instead of dropping the base.
-        self._pipeline_taint([st.slot])
         for req in self.scheduler:
             if req.u is None:
                 self.scheduler.cancel(req.sid)
@@ -1227,7 +1140,6 @@ class ExecPlane:
 
     def reset(self) -> None:
         self._drain_inflight()
-        self._pipeline_invalidate()
         self.arena = self._fresh_arena()
         self._freed = 0
         self.table.clear()
@@ -1297,8 +1209,7 @@ class ExecPlane:
             return y
 
         y = self._dispatch_decode(launch, list(vecs), tokens=1, block=False,
-                                  kind="step",
-                                  slots=[stats[sid].slot for sid in vecs])
+                                  kind="step")
         if self.learn_active():
             # The learn plane snapshots the post-step arena in ONE batched
             # D2H pull for the observe() accumulation that typically
@@ -1330,13 +1241,8 @@ class ExecPlane:
         # it reads the PRE-observe arena rows (or its own post-step
         # snapshot), so it must run before the arena rewrite below.
         self.on_observe(sid, st.slot, y, self.arena)
-        # Teacher-forcing writes arena rows outside wave bookkeeping; the
-        # mean-ensemble branch rewrites every ready session's feedback row.
-        if self.ensemble == "mean":
-            self._pipeline_taint(self.table.sessions[s].slot
-                                 for s in self.table.ready)
-        else:
-            self._pipeline_taint([st.slot])
+        # The mean-ensemble branch rewrites every ready session's feedback
+        # row.
         if self.ensemble == "mean":
             slots = jnp.asarray([self.table.sessions[s].slot
                                  for s in self.table.ready])
@@ -1382,8 +1288,7 @@ class ExecPlane:
         # measurement) — the per-token cost feeds the decode surface the
         # decode-aware planner budgets against.
         ys = self._dispatch_decode(launch, targets, tokens=n_steps,
-                                   block=False,
-                                   slots=[stats[s].slot for s in targets])
+                                   block=False)
         self.note_freerun(targets, n_steps)
         # ys: (n_steps, max_slots, d_out).  Nothing waits on the device
         # here: the wave's host copy starts now and is read once, when its

@@ -249,12 +249,12 @@ class OpenLoopServer:
                     min(h.n_decode - h.delivered
                         for h in (self._sessions[s] for s in want)))
             annotate(decode, tokens=max(1, k))
-            driven = [s for s in want if eng._ingest.input_depth(s) > 0]
+            driven = [s for s in want if eng.input_depth(s) > 0]
             free = [s for s in want if s not in driven]
             # Driven sessions advance through their queued open-loop
             # inputs; free ones free-run closed-loop.
             for s in driven:
-                rows = eng._ingest.pop_inputs(s, 1)
+                rows = eng.pop_inputs(s, 1)
                 if rows:
                     eng.decode_step({s: rows[0]})
             if free:

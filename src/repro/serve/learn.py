@@ -197,6 +197,20 @@ class LearnPlane:
     def pop(self, sid) -> None:
         self.state.pop(sid, None)
 
+    def export_state(self, hot) -> Dict[Hashable, _LearnState]:
+        """Every session's learn state with its buffered rows folded into
+        ``(G, C)`` — what an engine snapshot holds.  ``hot``: the sids that
+        hold a slot (a param-batched engine folds with the slot's
+        reservoir; any other session folds with the shared params)."""
+        for sid, ls in self.state.items():
+            self._fold_acc(ls.acc, self._session_params(sid)
+                           if sid in hot else self.params)
+        return dict(self.state)
+
+    def load_state(self, states: Dict[Hashable, _LearnState]) -> None:
+        """Install learn states that :meth:`export_state` gave out."""
+        self.state.update(states)
+
     def clear(self) -> None:
         self.state.clear()
         self.readouts.clear()

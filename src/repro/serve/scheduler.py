@@ -220,6 +220,17 @@ class WaveScheduler:
     def pending_sids(self):
         return [r.sid for r in self._queue]
 
+    def export_queue(self) -> Tuple[List[PrefillRequest], Optional[Hashable]]:
+        """The queued requests, oldest first (chunk cursors in ``done``), and
+        the committed deferral — what an engine snapshot holds."""
+        return list(self._queue), self._deferred
+
+    def load_queue(self, reqs, deferred: Optional[Hashable]) -> None:
+        """Re-queue :meth:`export_queue`'s requests and deferral."""
+        for req in reqs:
+            self.submit(req)
+        self._deferred = deferred
+
     # ---------------------------------------------------------------- waves
     def _next_len(self, req: PrefillRequest) -> int:
         """Length of the request's next chunk (the whole remainder when

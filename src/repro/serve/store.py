@@ -44,37 +44,24 @@ tested against.
 Paging is exact by construction: rows move through ``jax.device_get`` /
 host->device ``place_many`` with no dtype change, so a
 park -> spill -> restore round trip is bit-identical to never parking
-(pinned by test across all three tiers).
-
-The capstone is :func:`snapshot_engine` / :func:`restore_engine`: the whole
-serving process — arena, hot + parked session tables, admission queue with
-chunk cursors, un-collected decode buffers, and the cost-model artifact —
-serialized to one directory (npz + JSON manifest + ``_COMPLETE`` marker,
-atomic tmp-rename), so a process can be drained, upgraded, and resumed
-bit-exactly mid-workload.  Cold-tier records are *referenced*, not copied:
-they are already durable storage.
+(pinned by test across all three tiers).  An engine snapshot
+(``serve.snapshot``) embeds the host rows and references the cold records.
 """
 from __future__ import annotations
 
 import dataclasses
-import json
 import os
-import shutil
 from concurrent.futures import Future, ThreadPoolExecutor
 from typing import Dict, Hashable, List, Optional, Tuple
 
 import numpy as np
 
-__all__ = ["HostPool", "ParkedSession", "SessionStore",
-           "snapshot_engine", "restore_engine"]
+__all__ = ["HostPool", "ParkedSession", "SessionStore"]
 
 try:                                     # optional: URL-addressed cold tiers
     import fsspec as _fsspec
 except Exception:                        # pragma: no cover - env dependent
     _fsspec = None
-
-#: Snapshot manifest schema version (bump on incompatible layout changes).
-SNAPSHOT_VERSION = 1
 
 
 def _is_url(path: str) -> bool:
@@ -94,15 +81,6 @@ def _makedirs(path: str) -> None:
             fs.makedirs(p, exist_ok=True)
         return
     os.makedirs(path, exist_ok=True)
-
-
-def _sid_from_json(x):
-    """Invert JSON's tuple->list coercion: session ids may be strs, ints, or
-    (nested) tuples thereof — a list can never be a real sid (unhashable), so
-    every list in a manifest is a tuple that went through ``json.dump``."""
-    if isinstance(x, list):
-        return tuple(_sid_from_json(v) for v in x)
-    return x
 
 
 class HostPool:
@@ -143,7 +121,7 @@ class HostPool:
 @dataclasses.dataclass
 class ParkedSession:
     """One parked session: where its state lives and the engine's accounting
-    struct (``serve.engine.SessionStats``, carried opaquely — ``slot`` is -1
+    struct (``serve.ingest.SessionStats``, carried opaquely — ``slot`` is -1
     while parked; ``last_use`` is the LRU key for host->cold spill)."""
     stats: object
     tier: str                            # "host" | "cold"
@@ -261,6 +239,17 @@ class SessionStore:
 
     def tier_of(self, sid: Hashable) -> str:
         return self.table[sid].tier
+
+    def cursor(self) -> Tuple[int, int]:
+        """``(epoch, seq)``: where the next cold record is written."""
+        return self.epoch, self._seq
+
+    def resume_after(self, epoch: int) -> None:
+        """Continue a store snapshotted at ``epoch``: new cold records go to
+        a fresh epoch directory, so they never collide with the records the
+        snapshot references."""
+        self.epoch = int(epoch) + 1
+        self._seq = 0
 
     def stats(self) -> dict:
         host = sum(1 for e in self.table.values() if e.tier == "host")
@@ -402,360 +391,3 @@ class SessionStore:
         self.table.clear()
         self._spills.clear()
         self._prefetch.clear()
-
-
-# ====================================================================== #
-#  Engine snapshot / restore                                             #
-# ====================================================================== #
-
-def _params_arrays(params):
-    """(class name, present leaf names, {key: np array}) for a param struct —
-    the manifest records which optional leaves (w_fb / wfb_q) exist."""
-    from ..core.params import DiagParams
-    names = (("lam_q", "win_q", "wfb_q", "qtq")
-             if isinstance(params, DiagParams) else ("w", "w_in", "w_fb"))
-    present, arrays = [], {}
-    for name in names:
-        v = getattr(params, name)
-        if v is not None:
-            present.append(name)
-            arrays[f"params/{name}"] = np.asarray(v)
-    return type(params).__name__, present, arrays
-
-
-def _stats_rec(sid, st) -> dict:
-    return {"sid": sid, "slot": st.slot, "tp": st.tokens_prefilled,
-            "td": st.tokens_decoded, "pending": st.prefill_pending,
-            "last_use": st.last_use}
-
-
-def _stats_from_rec(rec):
-    from .ingest import SessionStats
-    return SessionStats(slot=rec["slot"], tokens_prefilled=rec["tp"],
-                        tokens_decoded=rec["td"],
-                        prefill_pending=rec["pending"],
-                        last_use=rec["last_use"])
-
-
-def snapshot_engine(engine, path: str) -> str:
-    """Serialize a whole serving engine to ``path`` (a directory).
-
-    Captures everything a bit-exact resume needs: params + readout, the
-    arena arrays, hot and parked session tables, the admission queue with
-    chunk cursors and parked ``(h0, y0)``, un-collected decode buffers and
-    wave metadata, the scheduler's committed deferral, and the cost-model
-    artifact (``cost.json``, the same schema ``WaveCostModel.from_artifact``
-    reads).  Host-tier parked rows are embedded; cold-tier records are
-    referenced by path (they are already durable).  The write is atomic:
-    ``<path>.tmp`` is renamed over ``path`` only after the ``_COMPLETE``
-    marker lands (the ``train/checkpoint.py`` contract).  Cumulative
-    ``stats()`` counters are *not* carried — a restored engine's telemetry
-    starts fresh.  Returns ``path``.
-    """
-    manifest: dict = {"version": SNAPSHOT_VERSION}
-    arrays: Dict[str, np.ndarray] = {}
-
-    pcls, present, parrs = _params_arrays(engine.params)
-    arrays.update(parrs)
-    manifest["params"] = {"class": pcls, "arrays": present,
-                          "cfg": dataclasses.asdict(engine.cfg),
-                          "n_real": int(getattr(engine.params, "n_real", 0))}
-    manifest["dtype"] = str(np.dtype(engine._dtype))
-    manifest["readout"] = engine.readout is not None
-    if engine.readout is not None:
-        arrays["readout/w_out"] = np.asarray(engine.readout.w_out)
-
-    sched = engine.scheduler
-    manifest["engine"] = {
-        "max_slots": engine.max_slots,
-        "bucket_min": sched.bucket_min,
-        "max_wave": sched.max_wave,
-        "chunk_max": sched.chunk_max,
-        "ensemble": engine.ensemble,
-        "autotune": engine._autotune,
-        "decode_slo_us": engine.decode_slo_us,
-        # "auto" survives the round trip: the restored engine re-resolves K
-        # per flush rather than freezing the last resolved value.
-        "decode_wave_tokens": ("auto" if engine._decode_k_auto
-                               else engine.decode_wave_tokens),
-        "pipeline_depth": engine.pipeline_depth,
-        "param_batch": engine._batched,
-        "park_host_rows": engine._park_host_rows,
-        "cold_dir": engine._cold_dir,
-        "learn": engine._learn,
-        "refit_alpha": engine._refit_alpha,
-        "refit_decay": engine._refit_decay,
-        "refit_washout": engine._refit_washout,
-        "drift_threshold": engine._drift_threshold,
-        "drift_beta": engine._drift_beta,
-        "growth_max_members": engine._growth_max,
-        "growth_sigma": engine._growth_sigma,
-        "growth_washout": engine._growth_washout,
-    }
-    manifest["use_clock"] = engine._use_clock
-
-    # Per-tenant readout pools + per-session streaming learn state.  Folded
-    # stats only: the engine folds each session's buffered rows first (the
-    # snapshot is already a host sync point).  Grown DPG ensemble members
-    # are NOT persisted — they are a drift response, and a restored engine
-    # re-grows them on drift; their teacher signal is in the stream, not
-    # the snapshot.
-    pools = []
-    for i, (key, w) in enumerate(engine._readouts.items()):
-        pools.append({"key": key})
-        arrays[f"pool{i}/w"] = np.asarray(w)
-    manifest["readout_pools"] = pools
-    learn_state = []
-    for i, (sid, ls) in enumerate(engine._learn_state.items()):
-        engine._fold_acc(ls.acc, engine._session_params(sid)
-                         if sid in engine.sessions else engine.params)
-        rec = {"sid": sid, "tenant": ls.tenant, "pairs": ls.acc.pairs,
-               "skip_left": ls.acc.skip_left, "drift": ls.acc.drift,
-               "steps_since_fb": ls.steps_since_fb, "dirty": ls.dirty,
-               "gram": ls.acc.gram is not None,
-               "last_fb": ls.last_fb is not None}
-        if ls.acc.gram is not None:
-            arrays[f"learn{i}/gram"] = np.asarray(ls.acc.gram)
-            arrays[f"learn{i}/cg"] = np.asarray(ls.acc.cg)
-        if ls.last_fb is not None:
-            arrays[f"learn{i}/last_fb"] = np.asarray(ls.last_fb)
-        learn_state.append(rec)
-    manifest["learn_state"] = learn_state
-
-    arrays["arena/states"] = np.asarray(engine.arena.states)
-    arrays["arena/y_prev"] = np.asarray(engine.arena.y_prev)
-    arrays["arena/active"] = np.asarray(engine.arena.active)
-    manifest["sessions"] = [_stats_rec(sid, st)
-                            for sid, st in engine.sessions.items()]
-
-    store = engine.store
-    if store is not None:
-        # The manifest references cold records by path: every in-flight
-        # spill write must be durable before the snapshot claims them.
-        store.drain_io()
-        parked, host_states, host_ys = [], [], []
-        for sid, entry in store.table.items():
-            rec = {"sid": sid, "tier": entry.tier,
-                   "stats": _stats_rec(sid, entry.stats)}
-            if entry.tier == "cold":
-                rec["path"] = entry.path
-            else:
-                rec["hrow"] = len(host_states)
-                host_states.append(store.pool.states[entry.row])
-                host_ys.append(store.pool.y_prev[entry.row])
-            parked.append(rec)
-        arrays["park/states"] = (np.stack(host_states) if host_states else
-                                 np.zeros((0, store.n), store.dtype))
-        arrays["park/y_prev"] = (np.stack(host_ys) if host_ys else
-                                 np.zeros((0, store.d_out), store.dtype))
-        manifest["store"] = {"epoch": store.epoch, "seq": store._seq,
-                             "parked": parked}
-
-    queue = []
-    for i, req in enumerate(sched._queue):
-        rec = {"sid": req.sid, "done": req.done}
-        for name in ("u", "y_teacher", "h0", "y0"):
-            v = getattr(req, name)
-            rec[name] = v is not None
-            if v is not None:
-                arrays[f"q{i}/{name}"] = np.asarray(v)
-        queue.append(rec)
-    manifest["queue"] = queue
-    manifest["deferred"] = sched._deferred
-
-    bufs = []
-    for i, (sid, chunks) in enumerate(engine._decode_buf.items()):
-        arrays[f"dec{i}"] = np.concatenate(
-            [np.asarray(c) for c in chunks], axis=0)
-        bufs.append({"sid": sid})
-    manifest["decode_buf"] = bufs
-    chunk_outs = []
-    for i, (sid, chunks) in enumerate(engine._chunk_outs.items()):
-        arrays[f"chunk{i}"] = np.concatenate(
-            [np.asarray(c) for c in chunks], axis=0)
-        chunk_outs.append({"sid": sid})
-    manifest["chunk_outs"] = chunk_outs
-    manifest["decode_meta"] = [
-        {"kind": m["kind"], "rows": m["rows"], "tokens": m["tokens"],
-         "us": m["us"], "fused": m["fused"],
-         "pending": sorted(m["_pending"], key=repr)}
-        for m in engine._decode_meta]
-    manifest["cost"] = None
-    if engine.cost_model is not None:
-        cm = engine.cost_model
-        manifest["cost"] = {
-            "key": None if cm.key is None else list(cm.key),
-            "base_us": cm.base_us, "per_token_us": cm.per_token_us,
-            "decode_base_us": cm.decode_base_us,
-            "decode_per_row_us": cm.decode_per_row_us,
-            "page_base_us": cm.page_base_us,
-            "page_per_row_us": cm.page_per_row_us,
-        }
-
-    tmp = str(path) + ".tmp"
-    if os.path.exists(tmp):
-        shutil.rmtree(tmp)
-    os.makedirs(tmp)
-    with open(os.path.join(tmp, "manifest.json"), "w") as f:
-        json.dump(manifest, f)
-    np.savez(os.path.join(tmp, "arrays.npz"), **arrays)
-    if engine.cost_model is not None:
-        engine.cost_model.to_artifact(os.path.join(tmp, "cost.json"))
-    with open(os.path.join(tmp, "_COMPLETE"), "w") as f:
-        f.write("ok")
-    if os.path.exists(path):
-        shutil.rmtree(path)
-    os.rename(tmp, path)
-    return str(path)
-
-
-def restore_engine(cls, path: str, *, mesh=None):
-    """Rebuild a serving engine from :func:`snapshot_engine` output.
-
-    The restored engine resumes bit-exactly: same params/readout, same
-    arena contents, same hot/parked/queued sessions (chunk cursors and the
-    scheduler's committed deferral included), same un-collected decode
-    buffers, and a cost model re-seeded from the snapshot's ``cost.json``.
-    The session store's epoch is bumped so new cold records never collide
-    with the ones the snapshot references.  ``mesh`` re-places the arena on
-    a (possibly different) device mesh — elastic restore, same contract as
-    ``train.checkpoint.restore``.  Bit-exactness assumes the same
-    ``jax_enable_x64`` setting as the snapshotting process (dtype
-    canonicalization happens on device_put).
-    """
-    import jax
-    import jax.numpy as jnp
-    from ..core.params import DiagParams, ESNConfig, Readout, StandardParams
-    from . import arena as arena_mod
-    from .cost import WaveCostModel
-    from .scheduler import PrefillRequest
-
-    if not os.path.exists(os.path.join(path, "_COMPLETE")):
-        raise FileNotFoundError(
-            f"no complete engine snapshot at {path!r} (missing _COMPLETE — "
-            f"interrupted write?)")
-    with open(os.path.join(path, "manifest.json")) as f:
-        m = json.load(f)
-    if m.get("version") != SNAPSHOT_VERSION:
-        raise ValueError(f"snapshot version {m.get('version')!r} != "
-                         f"{SNAPSHOT_VERSION} (incompatible layout)")
-    data = np.load(os.path.join(path, "arrays.npz"))
-
-    cfg = ESNConfig(**m["params"]["cfg"])
-    pcls = {"DiagParams": DiagParams,
-            "StandardParams": StandardParams}[m["params"]["class"]]
-    names = (("lam_q", "win_q", "wfb_q", "qtq") if pcls is DiagParams
-             else ("w", "w_in", "w_fb"))
-    kw = {name: (jnp.asarray(data[f"params/{name}"])
-                 if name in m["params"]["arrays"] else None)
-          for name in names}
-    if pcls is DiagParams:
-        params = DiagParams(cfg=cfg, n_real=m["params"]["n_real"], **kw)
-    else:
-        params = StandardParams(cfg=cfg, **kw)
-    readout = (Readout(jnp.asarray(data["readout/w_out"]))
-               if m["readout"] else None)
-
-    cost_model = None
-    if m["cost"] is not None:
-        c = dict(m["cost"])
-        key = c.pop("key")
-        cost_model = WaveCostModel.from_artifact(
-            os.path.join(path, "cost.json"),
-            key=None if key is None else tuple(key), **c)
-
-    ek = m["engine"]
-    eng = cls(params, max_slots=ek["max_slots"], readout=readout, mesh=mesh,
-              bucket_min=ek["bucket_min"], ensemble=ek["ensemble"],
-              chunk_max=ek["chunk_max"], autotune=ek["autotune"],
-              cost_model=cost_model, decode_slo_us=ek["decode_slo_us"],
-              decode_wave_tokens=ek["decode_wave_tokens"],
-              park_host_rows=ek["park_host_rows"], cold_dir=ek["cold_dir"],
-              pipeline_depth=ek.get("pipeline_depth", 2),
-              learn=ek.get("learn", False),
-              refit_alpha=ek.get("refit_alpha"),
-              refit_decay=ek.get("refit_decay", 1.0),
-              refit_washout=ek.get("refit_washout", 0),
-              drift_threshold=ek.get("drift_threshold"),
-              drift_beta=ek.get("drift_beta", 0.9),
-              growth_max_members=ek.get("growth_max_members", 3),
-              growth_sigma=ek.get("growth_sigma", 0.1),
-              growth_washout=ek.get("growth_washout", 64),
-              _param_batch=ek["param_batch"])
-    eng.scheduler.max_wave = ek["max_wave"]
-    eng._use_clock = m["use_clock"]
-
-    ar = arena_mod.SlotArena(states=jnp.asarray(data["arena/states"]),
-                             y_prev=jnp.asarray(data["arena/y_prev"]),
-                             active=jnp.asarray(data["arena/active"]))
-    if eng._plan is not None:
-        ar = arena_mod.SlotArena(
-            states=jax.device_put(ar.states, eng._plan.arena["states"]),
-            y_prev=jax.device_put(ar.y_prev, eng._plan.arena["y_prev"]),
-            active=jax.device_put(ar.active, eng._plan.arena["active"]))
-    eng.arena = ar
-
-    for rec in m["sessions"]:
-        sid = _sid_from_json(rec["sid"])
-        eng.sessions[sid] = _stats_from_rec(rec)
-        eng._slots[rec["slot"]] = sid
-
-    # Streaming learn state, then tenant readout pools (in that order: the
-    # slot re-scatter below resolves each hot session's pool key through
-    # its restored ``tenant``).  Both absent in pre-learn snapshots —
-    # ``get`` keeps those restorable.
-    for i, rec in enumerate(m.get("learn_state", [])):
-        from .learn import _GramAcc, _LearnState
-        acc = _GramAcc(pairs=rec["pairs"], skip_left=rec["skip_left"],
-                       drift=rec["drift"])
-        if rec["gram"]:
-            acc.gram = jnp.asarray(data[f"learn{i}/gram"])
-            acc.cg = jnp.asarray(data[f"learn{i}/cg"])
-        ls = _LearnState(tenant=_sid_from_json(rec["tenant"]),
-                         steps_since_fb=rec["steps_since_fb"],
-                         dirty=rec["dirty"], acc=acc)
-        if rec["last_fb"]:
-            ls.last_fb = data[f"learn{i}/last_fb"]
-        eng._learn_state[_sid_from_json(rec["sid"])] = ls
-    if m.get("readout_pools"):
-        for i, rec in enumerate(m["readout_pools"]):
-            eng._readouts[_sid_from_json(rec["key"])] = jnp.asarray(
-                data[f"pool{i}/w"])
-        eng._activate_pool()
-        eng._sync_slot_readouts([(sid, st.slot)
-                                 for sid, st in eng.sessions.items()])
-
-    if eng.store is not None and "store" in m:
-        st = m["store"]
-        eng.store.epoch = st["epoch"] + 1        # new records: new epoch dir
-        eng.store._seq = 0
-        hs, hy = data["park/states"], data["park/y_prev"]
-        for rec in st["parked"]:
-            sid = _sid_from_json(rec["sid"])
-            stats = _stats_from_rec(rec["stats"])
-            if rec["tier"] == "host":
-                eng.store.park_many([sid], hs[rec["hrow"]][None],
-                                    hy[rec["hrow"]][None], [stats])
-            else:
-                eng.store.table[sid] = ParkedSession(
-                    stats=stats, tier="cold", path=rec["path"])
-
-    for i, rec in enumerate(m["queue"]):
-        arrs = {name: (data[f"q{i}/{name}"] if rec[name] else None)
-                for name in ("u", "y_teacher", "h0", "y0")}
-        eng.scheduler.submit(PrefillRequest(
-            sid=_sid_from_json(rec["sid"]), done=rec["done"], **arrs))
-    if m["deferred"] is not None:
-        eng.scheduler._deferred = _sid_from_json(m["deferred"])
-
-    for i, rec in enumerate(m["decode_buf"]):
-        eng._decode_buf[_sid_from_json(rec["sid"])] = [data[f"dec{i}"]]
-    for i, rec in enumerate(m["chunk_outs"]):
-        eng._chunk_outs[_sid_from_json(rec["sid"])] = [
-            jnp.asarray(data[f"chunk{i}"])]
-    for rec in m["decode_meta"]:
-        eng._decode_meta.append(
-            {"kind": rec["kind"], "rows": rec["rows"],
-             "tokens": rec["tokens"], "us": rec["us"], "fused": rec["fused"],
-             "_pending": {_sid_from_json(s) for s in rec["pending"]}})
-    return eng

@@ -85,7 +85,7 @@ class Tracker:
     def log_wave(self, event: dict) -> None:
         """One serving event — a flat dict carrying ``kind`` (``prefill`` /
         ``decode`` / ``page`` / ``refit`` / ``growth`` / ``pipeline`` /
-        ``host_block`` / ``overlap_demote`` / ``admit`` / ``release`` /
+        ``host_block`` / ``admit`` / ``release`` /
         ``frontend``...) plus kind-specific fields."""
 
     def close(self) -> None:
@@ -174,7 +174,6 @@ class StatsAggregator(Tracker):
             "page_waves": 0, "page_rows": 0, "page_us_sum": 0.0,
             "promote_waves": 0, "demote_waves": 0,
             "inflight_peak": 0, "host_block_us": 0.0,
-            "overlap_demotes": 0,
             "refit_waves": 0, "refit_rows": 0,
             "refit_us_sum": 0.0, "growth_events": 0,
             "by_bucket": {}}
@@ -260,9 +259,6 @@ class StatsAggregator(Tracker):
     def _on_host_block(self, e: dict) -> None:
         self.c["host_block_us"] += e["us"]
 
-    def _on_overlap_demote(self, e: dict) -> None:
-        self.c["overlap_demotes"] += 1
-
     def _on_release(self, e: dict) -> None:
         self._last_decode_wall.pop(e.get("sid"), None)
 
@@ -314,7 +310,6 @@ class StatsAggregator(Tracker):
                                   else float(np.percentile(gaps, 95))),
             "pipeline_inflight_peak": s["inflight_peak"],
             "host_block_us": s["host_block_us"],
-            "overlap_demotes": s["overlap_demotes"],
             "refit_waves_total": s["refit_waves"],
             "refit_rows_total": s["refit_rows"],
             "refit_us_sum": s["refit_us_sum"],
@@ -364,7 +359,6 @@ class EngineStats:
     pipeline_inflight: int
     pipeline_inflight_peak: int
     host_block_us: float
-    overlap_demotes: int
     refit_waves_total: int
     refit_rows_total: int
     refit_us_sum: float

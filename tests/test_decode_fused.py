@@ -334,11 +334,11 @@ def _decode_by(path, params, readout, sig, sids):
     if path in ("closed_loop", "batched", "mean"):
         eng.decode_closed_loop(4)
     elif path == "interleave":
-        eng._decode_wave(sids)
+        eng._exec._decode_wave(sids)
     elif path == "driven":
         for i, s in enumerate(sids):
             eng.queue_inputs(s, sig[700 + i:705 + i, None])
-        eng._driven_wave(sids)
+        eng._exec._driven_wave(sids)
     else:
         eng.decode_step({s: sig[700 + i, None] for i, s in enumerate(sids)})
     (ys,) = outs

@@ -77,10 +77,10 @@ def test_decayed_fold_is_split_invariant_and_monotone(seed, lam, split):
     eng.flush()
     _stream(eng, "s", u, y, p, split)
     eng.refit("s")                     # fold window 1 at an arbitrary split
-    g1 = np.asarray(eng._learn_state["s"].acc.gram).copy()
+    g1 = np.asarray(eng._learn_plane.state["s"].acc.gram).copy()
     _stream(eng, "s", u, y, split, t_end)
-    ls = eng._learn_state["s"]
-    eng._fold_acc(ls.acc, model.params)
+    ls = eng._learn_plane.state["s"]
+    eng._learn_plane._fold_acc(ls.acc, model.params)
     # offline decayed reference over ALL rows [p, t_end) in one window
     states = esn_fn.run(model.params, u[:t_end])
     x = esn_fn.features(model.params, states)[p:]

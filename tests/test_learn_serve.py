@@ -134,8 +134,8 @@ def test_decayed_fold_matches_offline_decayed_weights():
     _stream(eng, "s", u, y, P, 200)
     eng.refit("s")
     _stream(eng, "s", u, y, 200, T)
-    ls = eng._learn_state["s"]
-    eng._fold_acc(ls.acc, model.params)
+    ls = eng._learn_plane.state["s"]
+    eng._learn_plane._fold_acc(ls.acc, model.params)
     # offline decayed reference over ALL rows [P, T)
     states = esn_fn.run(model.params, u[:T])
     x = esn_fn.features(model.params, states)[P:]
@@ -176,13 +176,13 @@ def test_interrupted_teacher_stream_pairs_only_contiguous_rows():
     eng.submit("s", u[:P])
     eng.flush()
     _stream(eng, "s", u, y, P, 150)
-    pairs_before = len(eng._learn_state["s"].acc.buf_h)
+    pairs_before = len(eng._learn_plane.state["s"].acc.buf_h)
     for t in range(150, 155):          # free-run: no observe
         eng.decode_step({"s": u[t]})
     eng.observe("s", y[155])           # 6 steps since last teacher event
-    assert len(eng._learn_state["s"].acc.buf_h) == pairs_before
+    assert len(eng._learn_plane.state["s"].acc.buf_h) == pairs_before
     _stream(eng, "s", u, y, 156, 200)  # contiguous again: pairs resume
-    assert len(eng._learn_state["s"].acc.buf_h) > pairs_before
+    assert len(eng._learn_plane.state["s"].acc.buf_h) > pairs_before
 
 
 # ------------------------------------------------- per-tenant readout pools
@@ -271,7 +271,7 @@ def test_release_drop_skips_state_gather():
     assert r.state is None and r.y_prev is None
     assert np.asarray(r.decoded["s"]).shape[0] == 1      # buffer still drains
     assert "s" not in eng.sessions
-    assert "s" not in eng._learn_state                   # learn state freed
+    assert "s" not in eng._learn_plane.state                   # learn state freed
 
 
 def test_evict_is_release_alias():
@@ -367,7 +367,7 @@ def test_drift_triggers_dpg_growth_and_member_votes():
         eng.observe("g", y[t] + rng.normal(scale=1.0, size=(1,)))
     eng.refit("g")
     assert eng.stats().growth_events >= 1
-    ls = eng._learn_state["g"]
+    ls = eng._learn_plane.state["g"]
     assert ls.members and ls.members[0].w is None        # no vote yet
     _stream(eng, "g", u, y, 150, 220)  # clean stream trains the member
     eng.refit("g")
@@ -391,7 +391,7 @@ def test_growth_capped_at_max_members():
             eng.decode_step({"g": u[t]})
             eng.observe("g", y[t] + rng.normal(scale=1.0, size=(1,)))
         eng.refit("g")
-    assert len(eng._learn_state["g"].members) == 1
+    assert len(eng._learn_plane.state["g"].members) == 1
     assert eng.stats().growth_events == 1
 
 

@@ -200,14 +200,15 @@ def test_donated_arena_retires_window_and_matches_sync(monkeypatch):
     with monkeypatch.context() as m:        # the engine decides donation
         m.setattr(jax, "default_backend", lambda: "tpu")     # at build time
         pipe, sync = _pair(params, readout, max_slots=4)
-    assert pipe._donate and sync._donate
     outs = []
     for eng in (pipe, sync):
         for i in range(4):
             eng.submit(i, sig[10 * i:10 * i + 100, None])
         eng.flush()
         for _ in range(4):
+            before = eng._exec.arena
             eng.decode_closed_loop(2)
+            assert before.states.is_deleted()       # donated to the decode
         outs.append(eng.collect_decoded().tokens)
     _assert_same_outputs(*outs)
 
@@ -472,8 +473,8 @@ def test_auto_decode_wave_tokens_survives_snapshot_round_trip():
     path = tempfile.mkdtemp(prefix="snap_auto_") + "/snap"
     eng.snapshot(path)
     back = ReservoirEngine.restore(path)
-    assert back._decode_k_auto
-    assert back.pipeline_depth == eng.pipeline_depth
+    assert back._exec.decode_k_auto
+    assert back._exec.pipeline_depth == eng._exec.pipeline_depth
 
 
 # ---------------------------------------------------- mixed-kind pad-up
@@ -579,10 +580,9 @@ def test_autotune_drains_inflight_predecessors_before_timing():
     # deliberately-async dispatch: a predecessor admitted into the window
     # by hand (autotune flushes never build one on their own)
     lazy = jax.numpy.ones((256, 256)) @ jax.numpy.ones((256, 256))
-    eng._inflight.append({"marker": lazy, "pred_us": 1.0,
-                          "slots": frozenset(), "arena_after": eng.arena})
+    eng._exec._inflight.append({"marker": lazy, "pred_us": 1.0})
     eng.submit("a", sig[:24, None])
     eng.flush()
-    assert len(eng._inflight) == 0          # drained, not leaked
+    assert len(eng._exec._inflight) == 0    # drained, not leaked
     recs = [r for r in eng.cost_model.records() if "t_bucket" in r]
     assert recs and all(r["us"] > 1.0 for r in recs)

@@ -40,7 +40,7 @@ def _engine(fitted, depth, sids, slots=3):
 
 
 def _occupancy(eng):
-    return np.array([s is not None for s in eng._slots], bool)
+    return np.array([s is not None for s in eng._table.slots], bool)
 
 
 @DEPTHS

@@ -5,6 +5,9 @@ The acceptance bar for the engine-monolith split:
 
 * one-way imports — planes never import each other sideways or upward
   (module-level regex over the plane sources, the PR-2 layering idiom);
+* the facade forwards no attribute (no ``__getattr__``): its public
+  surface is its own members, and the session store imports no serve
+  module at all;
 * the facade is *thin* (``serve/engine.py`` stays under 700 lines) and
   *bit-exact*: replaying the recorded mixed workload through the public
   surface reproduces the pre-refactor reference outputs <= 1e-5;
@@ -79,6 +82,7 @@ _FORBIDDEN = {
     "exec_plane.py": {"ingest", "learn", "engine", "frontend"},
     "learn.py": {"ingest", "exec_plane", "engine", "frontend"},
     "engine.py": {"frontend"},
+    "snapshot.py": {"frontend"},
     "frontend.py": {"exec_plane", "learn", "engine", "arena", "store",
                     "scheduler", "cost"},
 }
@@ -95,6 +99,39 @@ def test_plane_imports_are_one_way():
                 f"{fname} imports sibling {mod!r} at module level: "
                 f"{m.group(0)!r} — planes talk through facade-wired "
                 f"callbacks, not imports")
+
+
+def test_store_imports_no_serve_sibling():
+    """The store (the paging tier) imports no serve module, at any
+    indentation: the snapshot code that reached up into every plane lives
+    in ``snapshot.py``."""
+    src = (SERVE_DIR / "store.py").read_text()
+    m = re.search(r"^\s*(from\s+\.|(from|import)\s+repro\.serve\b)", src,
+                  re.MULTILINE)
+    assert m is None, f"store.py imports a serve module: {m.group(0)!r}"
+
+
+#: the facade's public surface that its planes own.
+_PLANE_SURFACE = ("sessions", "active_sessions", "ready_sessions",
+                  "free_slots", "max_queued", "queue_inputs", "input_depth",
+                  "pop_inputs", "state_of", "decode_step", "observe",
+                  "decode_closed_loop", "collect_decoded", "drift_rmse",
+                  "clear_decode_gaps")
+
+
+def test_facade_forwards_nothing_and_names_its_surface():
+    """No ``__getattr__`` on the facade: each public name is a member of
+    it, and a private plane name does not resolve on an engine."""
+    assert "__getattr__" not in vars(ReservoirEngine)
+    model, _, _ = _fitted()
+    eng = ReservoirEngine(model, max_slots=2, max_queued=3)
+    for name in _PLANE_SURFACE:
+        assert hasattr(eng, name), name
+    assert eng.max_queued == 3 and eng.free_slots == 2
+    assert eng.sessions == {} and eng.ready_sessions == []
+    for name in ("_decode_buf", "_inflight", "_learn_state", "_slots",
+                 "pipeline_depth"):
+        assert not hasattr(eng, name), name
 
 
 def test_facade_is_thin():
